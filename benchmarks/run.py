@@ -6,6 +6,7 @@ Usage::
                              [--engine {legacy,batched}] [--folds K]
                              [--smoke]
 
+Runs on a TPU only: it exits with an error when JAX finds no TPU.
 Prints ``name,us_per_call,derived`` CSV.  ``derived`` is the headline metric
 of the corresponding table (speedup x, rejection ratio, roofline fraction).
 
@@ -31,7 +32,8 @@ the fused fold-stack Pallas screening vs the jnp fallback at float32.
 
 ``--smoke`` runs only the fast engine + cv + cv-pallas + session +
 compile-audit + resource-audit + feature-shard comparison suites at
-reduced dimensions — the CI perf-regression gate.  The ``feature-shard``
+reduced dimensions.  The three audit suites below check invariants, not
+timings; ``tests/test_bench_gates.py`` runs them on the CPU.  The ``feature-shard``
 suite (also in the full run) raises if ``Plan(feature_shards=8)`` kept
 sets / betas drift from the single-device engine or if the sharded
 collective plan is anything but the single partial-fit psum.  The ``compile-audit`` suite (also in the
@@ -125,6 +127,15 @@ def _pop_flag(argv, name, default=None, has_value=True):
 
 
 def main() -> None:
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        # a timing from XLA:CPU or the Pallas interpreter is no measurement
+        # of this system; refuse rather than print one
+        raise SystemExit(f"benchmarks.run measures the TPU; JAX found "
+                         f"{dev.platform!r} ({dev.device_kind})")
     from . import paper_tables
     argv = sys.argv[1:]
     engine = _pop_flag(argv, "--engine", "legacy")

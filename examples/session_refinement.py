@@ -70,7 +70,7 @@ print(f"  warm == cold to {agree:.2e}; same selection: "
       f"{ref.lambda_ == cold.best_lambda}")
 
 # --- model-selection-as-a-service ------------------------------------------
-from repro.launch.sgl_serve import SGLServer
+from repro.launch.sgl_serve import SGLServer, raise_on_errors
 
 server = SGLServer(Plan(n_folds=3, n_lambdas=16, tol=1e-6, safety=1e-6,
                         max_iter=6000, check_every=50))
@@ -82,7 +82,7 @@ for X_job in (X, X):
     server.submit(X_job, yb, groups=[n] * G)
 server.submit(rng.standard_normal((N, p)), y, groups=[n] * G)
 t0 = time.perf_counter()
-results = server.drain()
+results = raise_on_errors(server.drain())
 t_serve = time.perf_counter() - t0
 print(f"\nserve       : {len(results)} jobs in {t_serve:.2f}s "
       f"({t_serve / len(results) * 1e3:.0f}ms/job)")

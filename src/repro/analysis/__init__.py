@@ -42,7 +42,7 @@ KNOWN_RULES = (
     "jaxpr/transfer-in-loop", "jaxpr/full-gemm-count",
     "jaxpr/pallas-on-f64",
     "compile/budget-exceeded", "compile/unpredicted-key",
-    "pallas/block-divisibility", "pallas/lane-misaligned",
+    "pallas/tpu-tiling",
     "pallas/mask-coverage", "pallas/f64-aval", "pallas/f64-gate",
     "pallas/no-kernel",
     "ast/host-sync-in-traced", "ast/host-sync-in-hot-loop",

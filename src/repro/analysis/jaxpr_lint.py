@@ -33,6 +33,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .findings import Finding
 
@@ -60,9 +61,9 @@ def _sub_jaxprs(eqn):
     pjit jaxpr, custom_*_call, pallas_call body, ...)."""
     for v in eqn.params.values():
         for x in _flatten(v):
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 yield x
 
 

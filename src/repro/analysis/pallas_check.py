@@ -1,18 +1,12 @@
 """Layer 2b: Pallas kernel audit — BlockSpec/grid contracts, ragged-tail
 mask coverage, and the f64 gate, all provable without a TPU.
 
-Three families of checks over every kernel in ``repro.kernels``:
+Checks over every kernel in ``repro.kernels``:
 
-  * ``pallas/block-divisibility``  every ``pallas_call`` in the trace must
-    tile its (padded) operands exactly: ``array_shape % block_shape == 0``
-    per dimension.  The engine's pow2 bucket contract exists precisely so
-    this holds; a non-divisible BlockSpec would read garbage lanes on TPU
-    (interpret mode masks the bug, which is why this is a static rule).
-  * ``pallas/lane-misaligned``     a trailing block dimension >= 128 that
-    is not a multiple of 128 straddles TPU lanes.  (Small trailing blocks
-    — (G, 1) reductions, (K, L) radii tiles — are deliberately exempt:
-    sub-lane tiles are legal, it is *misaligned large* tiles that are
-    not.)
+  * ``pallas/tpu-tiling``          the minor two dims of every block must
+    be multiples of (8, 128) or equal the whole array dim — the rule
+    Mosaic enforces when it compiles for a TPU, which interpret mode never
+    checks.
   * ``pallas/f64-aval``            no float64 aval may reach a kernel
     signature; the kernels are f32-only by contract
     (``_require_f32_for_pallas``) and f64 operands would be silently
@@ -21,7 +15,9 @@ Three families of checks over every kernel in ``repro.kernels``:
     slot with 1e30 and compare the interpret-mode kernel against the
     pure-jnp oracle (``kernels/ref.py``) on ragged, non-multiple-of-128
     shapes.  If a ragged-tail mask misses a slot, the poison propagates
-    and the outputs diverge.
+    and the outputs diverge.  Interpret mode fills a block's overhang past
+    its operand with NaN, so the same comparison proves that edge blocks
+    only feed dropped outputs or masked lanes.
   * ``pallas/f64-gate``            the screening entry points must REFUSE
     ``use_pallas=True`` on f64 inputs (TypeError), not silently downcast.
 """
@@ -34,8 +30,7 @@ import jax.numpy as jnp
 from .findings import Finding
 from .jaxpr_lint import iter_eqns
 
-# trailing block dims below this are sub-lane reduction tiles, always legal
-_LANE = 128
+_SUBLANE, _LANE = 8, 128
 
 
 def _pallas_eqns(closed):
@@ -53,28 +48,25 @@ def check_traceable(fn, *args, name: str) -> list:
         found_any = True
         gm = eqn.params["grid_mapping"]
         for bm in gm.block_mappings:
-            shape = tuple(bm.array_shape_dtype.shape)
-            dtype = bm.array_shape_dtype.dtype
-            block = tuple(bm.block_shape)
+            shape = tuple(bm.array_aval.shape)
+            dtype = bm.array_aval.dtype
+            block = tuple(getattr(b, "block_size", b)
+                          for b in bm.block_shape)
             if np.dtype(dtype) == np.float64:
                 findings.append(Finding(
                     "pallas/f64-aval", "error", name,
                     f"float64 aval {shape} reaches a pallas_call operand "
                     f"of {name}; kernels are f32-only by contract"))
-            for dim, (s, b) in enumerate(zip(shape, block)):
-                if not isinstance(b, int):
-                    continue          # mapped/None dims
-                if b > 0 and s % b != 0:
+            for dim, align in ((-1, _LANE), (-2, _SUBLANE)):
+                if len(block) < -dim or not isinstance(block[dim], int):
+                    continue
+                b = block[dim]
+                if b != shape[dim] and b % align != 0:
                     findings.append(Finding(
-                        "pallas/block-divisibility", "error", name,
-                        f"operand {shape} of {name} not divisible by "
-                        f"block {block} (dim {dim}: {s} % {b} != 0)"))
-            if block and isinstance(block[-1], int) \
-                    and block[-1] >= _LANE and block[-1] % _LANE != 0:
-                findings.append(Finding(
-                    "pallas/lane-misaligned", "error", name,
-                    f"trailing block dim {block[-1]} of {name} is >= "
-                    f"{_LANE} but not a multiple of {_LANE}"))
+                        "pallas/tpu-tiling", "error", name,
+                        f"block {block} of operand {shape} in {name}: dim "
+                        f"{len(block) + dim} is {b}, neither a multiple of "
+                        f"{align} nor the whole dim"))
     if not found_any:
         findings.append(Finding(
             "pallas/no-kernel", "warning", name,
@@ -103,13 +95,13 @@ def _structural_cases():
     rng = np.random.default_rng(0)
     spec = _ragged_spec()
     G, n_max = spec.num_groups, int(np.max(np.asarray(spec.sizes)))
-    mask = jnp.asarray(np.asarray(spec.pad_mask))
+    mask = jnp.asarray(np.asarray(spec.pad_mask).T)
     f32 = jnp.float32
     X = jnp.asarray(rng.standard_normal((137, 37)), f32)
     v = jnp.asarray(rng.standard_normal(137), f32)
-    c_pad = jnp.asarray(rng.standard_normal((G, n_max)), f32)
-    c_grid = jnp.asarray(rng.standard_normal((5, G, n_max)), f32)
-    c_folds = jnp.asarray(rng.standard_normal((3, 5, G, n_max)), f32)
+    c_pad = jnp.asarray(rng.standard_normal((n_max, G)), f32)
+    c_grid = jnp.asarray(rng.standard_normal((n_max, 5, G)), f32)
+    c_folds = jnp.asarray(rng.standard_normal((n_max, 3, 5, G)), f32)
     C = jnp.asarray(rng.standard_normal((2, 3, 37)), f32)
     radii = jnp.asarray(rng.random((2, 3)), f32)
     col_n = jnp.asarray(rng.random((2, 37)) + 0.5, f32)
@@ -120,7 +112,6 @@ def _structural_cases():
 
     return [
         ("kernels.xtv", w(ops.xtv), (X, v)),
-        ("kernels.screen_norms", w(ops.screen_norms), (c_pad, mask)),
         ("kernels.screen_norms_batched", w(ops.screen_norms_batched),
          (c_grid, mask)),
         ("kernels.screen_norms_folds", w(ops.screen_norms_folds),
@@ -141,7 +132,7 @@ def _mask_coverage() -> list:
     rng = np.random.default_rng(1)
     spec = _ragged_spec()
     G, n_max = spec.num_groups, int(np.max(np.asarray(spec.sizes)))
-    mask_np = np.asarray(spec.pad_mask)
+    mask_np = np.asarray(spec.pad_mask).T          # slot-major (n_max, G)
     mask = jnp.asarray(mask_np)
 
     def poisoned(shape, mask_b):
@@ -161,19 +152,19 @@ def _mask_coverage() -> list:
 
     # screen_norms: oracle sees clean data (mask zeroes it), kernel sees
     # poison in the masked-out slots
-    c_np = rng.standard_normal((G, n_max)).astype(np.float32)
-    c_clean = jnp.asarray(np.where(mask_np, c_np, 0.0))
-    c_poison = jnp.asarray(np.where(mask_np, c_np, _POISON))
+    slot = mask_np[:, None, :]
+    c_np = rng.standard_normal((n_max, 5, G)).astype(np.float32)
+    c_clean = jnp.asarray(np.where(slot, c_np, 0.0))
+    c_poison = jnp.asarray(np.where(slot, c_np, _POISON))
     want = ref.screen_norms_ref(c_clean, mask)
-    got = ops.screen_norms(c_poison, mask, interpret=True)
-    compare("kernels.screen_norms", got[0], want[0])
-    compare("kernels.screen_norms", got[1], want[1])
+    got = ops.screen_norms_batched(c_poison, mask, interpret=True)
+    compare("kernels.screen_norms_batched", got[0], want[0])
+    compare("kernels.screen_norms_batched", got[1], want[1])
 
-    cf_np = rng.standard_normal((3, 5, G, n_max)).astype(np.float32)
-    cf_clean = jnp.asarray(np.where(mask_np, cf_np, 0.0))
-    cf_poison = jnp.asarray(np.where(mask_np, cf_np, _POISON))
-    want0 = jax.vmap(jax.vmap(lambda c: ref.screen_norms_ref(c, mask)))(
-        cf_clean)
+    cf_np = rng.standard_normal((n_max, 3, 5, G)).astype(np.float32)
+    cf_clean = jnp.asarray(np.where(slot[:, None], cf_np, 0.0))
+    cf_poison = jnp.asarray(np.where(slot[:, None], cf_np, _POISON))
+    want0 = ref.screen_norms_ref(cf_clean, mask)
     got0 = ops.screen_norms_folds(cf_poison, mask, interpret=True)
     compare("kernels.screen_norms_folds", got0[0], want0[0])
     compare("kernels.screen_norms_folds", got0[1], want0[1])
@@ -188,7 +179,7 @@ def _mask_coverage() -> list:
     got1 = ops.dpc_screen_folds(C, radii, col_n, interpret=True)
     compare("kernels.dpc_screen_folds", got1, want1, atol=0)
 
-    v_np = rng.standard_normal((G, n_max)).astype(np.float32)
+    v_np = rng.standard_normal((n_max, G)).astype(np.float32)
     v_clean = jnp.asarray(np.where(mask_np, v_np, 0.0))
     v_poison = jnp.asarray(np.where(mask_np, v_np, _POISON))
     t_l1 = jnp.float32(0.3)
@@ -197,8 +188,8 @@ def _mask_coverage() -> list:
     got2 = ops.sgl_prox_padded(v_poison, mask, t_l1, t_g, interpret=True)
     compare("kernels.sgl_prox_padded", got2, want2)
 
-    # xtv pads (N, p) internally with zeros; ragged (137, 37) covers the
-    # tail-lane path
+    # xtv streams X unpadded; ragged (137, 37) covers the overhanging
+    # tail (NaN-filled in interpret mode)
     X_np = rng.standard_normal((137, 37)).astype(np.float32)
     vv = rng.standard_normal(137).astype(np.float32)
     want3 = ref.xtv_ref(jnp.asarray(X_np), jnp.asarray(vv))

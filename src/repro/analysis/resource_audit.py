@@ -56,6 +56,7 @@ from typing import Iterable, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Var
 
 from .findings import Finding
 from .compile_audit import (ProblemShape, _pow2_ceil, chunk_lengths,
@@ -100,7 +101,7 @@ def _aval_bytes(aval) -> int:
 
 
 def _is_var(v) -> bool:
-    return isinstance(v, jax.core.Var)
+    return isinstance(v, Var)
 
 
 #: primitives whose output is *provably* never a fresh buffer — erased at

@@ -2,10 +2,9 @@
 
 The AFQ-Insight-shaped workload: fit/predict/score estimators whose ``fit``
 runs K-fold model selection over a lambda grid and refits at the selected
-regularization.  No sklearn dependency — the classes follow its estimator
-protocol (constructor stores hyperparameters untouched; ``fit`` sets
-trailing-underscore attributes) so they drop into pipelines that only rely
-on duck typing.
+regularization.  The classes are ``sklearn.base.BaseEstimator``s
+(constructor stores hyperparameters untouched; ``fit`` sets
+trailing-underscore attributes).
 
   SGLRegressor   one (lambda, alpha) Sparse-Group Lasso fit
   SGLClassifier  one (lambda, alpha) sparse-group LOGISTIC regression fit
@@ -13,10 +12,9 @@ on duck typing.
   SGLCV          fold-batched K-fold CV over the grid, then refit
   NNLassoCV      the nonnegative-Lasso analogue (DPC screening)
 
-All estimators implement sklearn's ``get_params`` / ``set_params``
-introspection (derived from the constructor signature), so they survive
-``sklearn.base.clone`` and slot into ``GridSearchCV`` without inheriting
-from sklearn base classes.
+``BaseEstimator`` derives ``get_params`` / ``set_params`` from the
+constructor signature and supplies the estimator tags, so the estimators
+survive ``sklearn.base.clone`` and slot into ``GridSearchCV``.
 
 Each CV estimator builds a ``core.Problem`` + ``core.Plan`` and runs them
 through a ``core.SGLSession`` (exposed after ``fit`` as ``session_``, so
@@ -34,10 +32,9 @@ final refit intercept still comes from the full sample).
 """
 from __future__ import annotations
 
-import inspect
-
 import numpy as np
 import jax.numpy as jnp
+from sklearn.base import BaseEstimator
 
 from .core import (Plan, Problem, SGLSession, as_group_spec, solve_nn_lasso,
                    solve_sgl, spectral_norm)
@@ -56,39 +53,7 @@ def _center(X, y, fit_intercept: bool):
     return X - x_mean, y - y_mean, x_mean, y_mean
 
 
-class _ParamsMixin:
-    """sklearn estimator introspection without the sklearn dependency.
-
-    ``get_params`` enumerates the constructor signature (sklearn's
-    convention: every ``__init__`` argument is stored verbatim on an
-    attribute of the same name), which is exactly what ``sklearn.base.clone``
-    and ``GridSearchCV`` call; ``set_params(**kw)`` validates names against
-    the same signature so typos fail loudly instead of silently fitting
-    defaults."""
-
-    @classmethod
-    def _param_names(cls):
-        sig = inspect.signature(cls.__init__)
-        return [n for n, prm in sig.parameters.items()
-                if n != "self" and prm.kind not in (prm.VAR_POSITIONAL,
-                                                    prm.VAR_KEYWORD)]
-
-    def get_params(self, deep: bool = True):
-        return {n: getattr(self, n) for n in self._param_names()}
-
-    def set_params(self, **params):
-        valid = set(self._param_names())
-        for name, value in params.items():
-            if name not in valid:
-                raise ValueError(
-                    f"invalid parameter {name!r} for estimator "
-                    f"{type(self).__name__}; valid parameters: "
-                    f"{sorted(valid)}")
-            setattr(self, name, value)
-        return self
-
-
-class _LinearBase(_ParamsMixin):
+class _LinearBase(BaseEstimator):
     """Shared predict/score for fitted linear models."""
 
     coef_: np.ndarray
@@ -140,7 +105,7 @@ class SGLRegressor(_LinearBase):
         return self
 
 
-class SGLClassifier(_ParamsMixin):
+class SGLClassifier(BaseEstimator):
     """Sparse-group logistic regression at one (lam, alpha).
 
     The SGL penalty on the binomial negative log-likelihood, solved by the

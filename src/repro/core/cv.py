@@ -71,7 +71,7 @@ from .fenchel import shrink, weighted_l1
 from .groups import GroupSpec, group_norms
 from .lambda_max import lambda_max_sgl
 from .losses import SQUARED, Loss, get_loss
-from .linalg import group_spectral_norms, spectral_norm
+from .linalg import group_spectral_norms, mm, spectral_norm
 from .path import _bucket
 from .path_engine import (EngineStats, _expand_set, _feature_bucket,
                           _pallas_active, _pow2_len, margin_fill_nn,
@@ -219,7 +219,7 @@ def _screen_folds_sgl(X, Y, spec, alpha, rem, lam_bars, lam_maxs, theta_bars,
                                        safety=safety, mus=mus,
                                        use_pallas=use_pallas)
     if screen == "gapsafe":
-        fit = beta_prev @ X.T
+        fit = mm(beta_prev, X.T)
         if mus is not None:     # centered fit: (X - 1 mu^T) beta
             fit = fit - jnp.sum(beta_prev * mus, axis=1)[:, None]
         resid = Y - masks * fit
@@ -250,7 +250,7 @@ def _screen_folds_nn(X, Y, rem, lam_bars, lam_maxs, theta_bars, n_bound,
     fk, _ = dpc_screen_grid_folds(X, Y, rem, theta_bars, n_vecs, col_n_f,
                                   safety=safety, use_pallas=use_pallas)
     if screen == "gapsafe":
-        resid = Y - masks * (beta_prev @ X.T)
+        resid = Y - masks * mm(beta_prev, X.T)
         pen = jnp.sum(beta_prev, axis=1)         # beta >= 0 => l1 = sum
         radii = jax.vmap(gap_safe_grid_radii)(Y, rem, theta_bars, resid,
                                               pen) * (1.0 + safety)
@@ -280,7 +280,7 @@ def _screen_folds_sgl_feat(fops, Xs, Y, spec, specs_s, alpha, rem, lam_bars,
         else:
             def body(loc):
                 Xb, bb, mub = loc
-                return bb @ Xb.T, jnp.sum(bb * mub, axis=1)
+                return mm(bb, Xb.T), jnp.sum(bb * mub, axis=1)
             fit, corr = fops.fsum(body, (Xs, beta_s, mus_s))
             fit = fit - corr[:, None]
         resid = Y - masks * fit
@@ -542,7 +542,8 @@ class _FoldEngine:
         host sync per launch); the heavy outputs are sliced per fold to the
         accepted rows only, so rejected speculative rows are never
         transferred.  Row 0 of every fold is solved on a provably safe
-        superset, so kk >= 1 guarantees progress."""
+        superset, so kk >= 1 guarantees progress; a row 0 that stopped at
+        ``max_iter`` uncertified is kept and counted in ``n_uncertified``."""
         ts = time.perf_counter()
         betas_b, thetas_b, cthetas_b, good_b, iters_b = launch.outputs
         good_np = np.asarray(good_b)                 # one host sync
@@ -552,6 +553,7 @@ class _FoldEngine:
             kk = int(np.argmin(good)) if not good.all() else mk
             if kk == 0:
                 kk = 1
+                self.stats.n_uncertified += 1
             self.stats.n_rejected += int(mk - kk)
             col_idx = launch.col_idxs[t]
             rows = np.zeros((kk, self.p))
@@ -705,7 +707,7 @@ class _SGLFoldEngine(_FoldEngine):
             self.fshard = fshard
             self.fops = _fs.feature_ops(
                 fshard.n_shards, _fs.resolve_feature_mesh(fshard.n_shards))
-            self.Xs = jnp.asarray(fshard.stack_columns(self.X_np))
+            self.Xs = self.fops.place(fshard.stack_columns(self.X_np))
             self.specs_s = fshard.specs_stacked
             self.col_n_sf = jnp.asarray(
                 fshard.shard_features(np.asarray(col_n_f)))
@@ -826,7 +828,7 @@ class _NNFoldEngine(_FoldEngine):
             self.fshard = fshard
             self.fops = _fs.feature_ops(
                 fshard.n_shards, _fs.resolve_feature_mesh(fshard.n_shards))
-            self.Xs = jnp.asarray(fshard.stack_columns(self.X_np))
+            self.Xs = self.fops.place(fshard.stack_columns(self.X_np))
             self.col_n_sf = jnp.asarray(
                 fshard.shard_features(np.asarray(col_n_f)))
 
@@ -993,17 +995,17 @@ def sgl_fold_paths(X, y, spec: GroupSpec, alpha, masks, lambdas, *,
     t0 = time.perf_counter()
     masks_d = jnp.asarray(masks_np, X.dtype)
     Y = masks_d * jnp.asarray(y_rows_np, X.dtype)             # (K, N)
-    col2_f = masks_d @ (X * X)                                # (K, p)
+    col2_f = mm(masks_d, X * X)                                # (K, p)
     if centered:
         mus_d = jnp.asarray(mus, X.dtype)
         # centered correlations / norms via rank-one corrections:
         # (X - 1 mu^T)^T v = X^T v - mu (1^T v);  sum m (x-mu)^2 = col2 - n mu^2
-        xty_f = Y @ X - jnp.sum(Y, axis=1)[:, None] * mus_d
+        xty_f = mm(Y, X) - jnp.sum(Y, axis=1)[:, None] * mus_d
         n_train = jnp.sum(masks_d, axis=1)
         col2_f = jnp.maximum(col2_f - n_train[:, None] * mus_d ** 2, 0.0)
     else:
         mus_d = None
-        xty_f = Y @ X                                         # (K, p)
+        xty_f = mm(Y, X)                                      # (K, p)
     lam_max_f, g_star_f = jax.vmap(
         lambda c: lambda_max_sgl(spec, c, alpha))(xty_f)
     col_n_f = jnp.sqrt(col2_f)
@@ -1024,7 +1026,7 @@ def sgl_fold_paths(X, y, spec: GroupSpec, alpha, masks, lambdas, *,
                               X.dtype)
     W = shrink(xty_f / lam_max_div[:, None])
     w_star = jnp.where(spec.group_ids[None, :] == g_star_f[:, None], W, 0.0)
-    n_bound = w_star @ X.T                                    # (K, N)
+    n_bound = mm(w_star, X.T)                                 # (K, N)
     if centered:
         n_bound = n_bound - jnp.sum(w_star * mus_d, axis=1)[:, None]
     n_bound = masks_d * n_bound
@@ -1100,9 +1102,9 @@ def nn_fold_paths(X, y, masks, lambdas, *, screen: str = "dpc", tol=1e-9,
     t0 = time.perf_counter()
     masks_d = jnp.asarray(masks_np, X.dtype)
     Y = masks_d * jnp.asarray(y_rows_np, X.dtype)
-    xty_f = Y @ X
+    xty_f = mm(Y, X)
     lam_max_f, i_star_f = jax.vmap(lambda_max_nn)(xty_f)
-    col_n_f = jnp.sqrt(masks_d @ (X * X))
+    col_n_f = jnp.sqrt(mm(masks_d, X * X))
     lam_max_np = np.asarray(lam_max_f, dtype=float)
     n_bound = masks_d * X[:, np.asarray(i_star_f)].T          # (K, N)
     jax.block_until_ready((col_n_f, n_bound))

@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .estimation import DualBall, estimate_dual_ball
+from .linalg import mm
 
 
 def lambda_max_nn(xty: jnp.ndarray):
@@ -29,7 +30,7 @@ def nn_dual_objective(y, theta, lam):
 
 
 def nn_primal_objective(X, y, beta, lam):
-    r = y - X @ beta
+    r = y - mm(X, beta)
     return 0.5 * jnp.vdot(r, r) + lam * jnp.sum(beta)   # beta >= 0 => l1 = sum
 
 
@@ -42,20 +43,28 @@ def normal_vector_nn(X, y, lam_bar, lam_max, theta_bar, i_star) -> jnp.ndarray:
 def dpc_screen(X, ball: DualBall, col_norms, safety: float = 0.0):
     """Theorem 22.  Returns feat_keep (p,) bool: False => certified zero."""
     r = ball.radius * (1.0 + safety)
-    omega = X.T @ ball.center + r * col_norms
+    omega = mm(X.T, ball.center) + r * col_norms
     return omega >= 1.0
 
 
 def dpc_screen_grid(X, y, lambdas, theta_bar, n_vec, col_norms,
-                    safety: float = 0.0):
+                    safety: float = 0.0, use_pallas: bool = False):
     """Theorem 22 for a WHOLE remaining lambda grid in one GEMM.
 
     Same center/radius algebra as the SGL grid rule (Theorem 21 shares the
-    Theorem 12 geometry); returns (feat_keep (L, p), radii (L,))."""
-    from .screening import grid_ball_geometry
+    Theorem 12 geometry); returns (feat_keep (L, p), radii (L,)).
+    ``use_pallas`` fuses the threshold through the fold-stack kernel as a
+    one-fold stack (float32 only, as ``dpc_screen_grid_folds``)."""
+    from .screening import _require_f32_for_pallas, grid_ball_geometry
     centers, radii = grid_ball_geometry(y, lambdas, theta_bar, n_vec)
     radii = radii * (1.0 + safety)
-    omega = centers @ X + radii[:, None] * col_norms[None, :]
+    C = mm(centers, X)
+    if use_pallas:
+        _require_f32_for_pallas(C.dtype)
+        from ..kernels import ops as _kops
+        return _kops.dpc_screen_folds(C[None], radii[None],
+                                      col_norms[None])[0], radii
+    omega = C + radii[:, None] * col_norms[None, :]
     return omega >= 1.0, radii
 
 
@@ -76,7 +85,7 @@ def dpc_screen_grid_folds(X, Y, lambdas, Theta_bar, N_vecs, col_norms_f,
     N = Y.shape[1]
     centers, radii = grid_ball_geometry_folds(Y, lambdas, Theta_bar, N_vecs)
     radii = radii * (1.0 + safety)
-    C = (centers.reshape(K * L, N) @ X).reshape(K, L, X.shape[1])
+    C = mm(centers.reshape(K * L, N), X).reshape(K, L, X.shape[1])
     if use_pallas:
         _require_f32_for_pallas(C.dtype)
         from ..kernels import ops as _kops
@@ -111,7 +120,7 @@ def dpc_screen_grid_feat(ops, Xs, y, lambdas, theta_bar, n_vec,
 
     def body(loc, centers, radii):
         Xb, cn = loc
-        omega = centers @ Xb + radii[:, None] * cn[None, :]
+        omega = mm(centers, Xb) + radii[:, None] * cn[None, :]
         return omega >= 1.0
 
     return ops.fmap(body, (Xs, col_norms_s), centers, radii), radii
@@ -130,7 +139,8 @@ def dpc_screen_grid_folds_feat(ops, Xs, Y, lambdas, Theta_bar, N_vecs,
 
     def body(loc, centers, radii):
         Xb, cn = loc
-        C = (centers.reshape(K * L, N) @ Xb).reshape(K, L, Xb.shape[1])
+        C = mm(centers.reshape(K * L, N), Xb).reshape(K, L,
+                                                         Xb.shape[1])
         omega = C + radii[:, :, None] * cn[:, None, :]
         return omega >= 1.0
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from .fenchel import shrink
 from .groups import GroupSpec, broadcast_to_features
+from .linalg import mm
 
 
 @jax.tree_util.register_pytree_node_class
@@ -60,10 +61,10 @@ def normal_vector_sgl(X, y, spec: GroupSpec, lam_bar, lam_max, theta_bar,
     """
     at_max = jnp.asarray(lam_bar >= lam_max * (1.0 - 1e-12))
     n_interior = y / lam_bar - theta_bar
-    w = shrink(X.T @ (y / lam_max))
+    w = shrink(mm(X.T, y / lam_max))
     w_star = jnp.where(broadcast_to_features(spec, jnp.arange(spec.num_groups)
                                              ) == g_star, w, 0.0)
-    n_boundary = X @ w_star
+    n_boundary = mm(X, w_star)
     return jnp.where(at_max, n_boundary, n_interior)
 
 

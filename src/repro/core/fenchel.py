@@ -15,6 +15,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .groups import GroupSpec, group_norms, group_max_abs
+from .linalg import mm
 
 
 def shrink(w: jnp.ndarray, gamma=1.0) -> jnp.ndarray:
@@ -81,7 +82,7 @@ def sgl_penalty(spec: GroupSpec, beta, alpha) -> jnp.ndarray:
 
 def sgl_primal_objective(X, y, beta, spec: GroupSpec, lam, alpha):
     """Objective of problem (3)."""
-    r = y - X @ beta
+    r = y - mm(X, beta)
     pen = sgl_penalty(spec, beta, alpha)
     return 0.5 * jnp.vdot(r, r) + lam * pen
 

@@ -28,7 +28,7 @@ from .estimation import DualBall, estimate_dual_ball, gap_safe_ball, normal_vect
 from .fenchel import sgl_dual_objective, sgl_primal_objective
 from .groups import GroupSpec
 from .lambda_max import dual_scaling_sgl, lambda_max_sgl
-from .linalg import column_norms, group_spectral_norms, spectral_norm
+from .linalg import column_norms, group_spectral_norms, mm, spectral_norm
 from .screening import tlfre_screen
 from .solver import solve_nn_lasso, solve_sgl
 
@@ -103,7 +103,7 @@ def sgl_path(X, y, spec: GroupSpec, alpha, *, lambdas=None, n_lambdas=100,
     N, p = X.shape
 
     t0 = time.perf_counter()
-    xty = X.T @ y
+    xty = mm(X.T, y)
     lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
     lam_max = float(lam_max)
     col_n = column_norms(X)
@@ -194,8 +194,8 @@ def sgl_path(X, y, spec: GroupSpec, alpha, *, lambdas=None, n_lambdas=100,
             iters[j] = int(res.iters)
             # exact dual: residual from the REDUCED matrix (screened coefs
             # are provably zero), feasibility scaling over the full X
-            rho = (y - X_sub @ res.beta) / lam
-            s = dual_scaling_sgl(spec, X.T @ rho, alpha)
+            rho = (y - mm(X_sub, res.beta)) / lam
+            s = dual_scaling_sgl(spec, mm(X.T, rho), alpha)
             theta_bar = s * rho
             jax.block_until_ready(theta_bar)
         solve_time += time.perf_counter() - ts
@@ -237,7 +237,7 @@ def nn_lasso_path(X, y, *, lambdas=None, n_lambdas=100, min_ratio=0.01,
     N, p = X.shape
 
     t0 = time.perf_counter()
-    xty = X.T @ y
+    xty = mm(X.T, y)
     lam_max, i_star = lambda_max_nn(xty)
     lam_max = float(lam_max)
     if lam_max <= 0:
@@ -311,8 +311,8 @@ def nn_lasso_path(X, y, *, lambdas=None, n_lambdas=100, min_ratio=0.01,
             beta_full = np.zeros(p)
             beta_full[col_idx] = np.asarray(res.beta)[:len(col_idx)]
             iters[j] = int(res.iters)
-            rho = (y - X_sub @ res.beta) / lam
-            s = dual_scaling_nn(X.T @ rho)
+            rho = (y - mm(X_sub, res.beta)) / lam
+            s = dual_scaling_nn(mm(X.T, rho))
             theta_bar = s * rho
             jax.block_until_ready(theta_bar)
         solve_time += time.perf_counter() - ts

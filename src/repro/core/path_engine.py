@@ -70,10 +70,10 @@ from .dpc import (dpc_screen_grid, dpc_screen_grid_feat, dual_scaling_nn,
                   lambda_max_nn, normal_vector_nn)
 from .estimation import normal_vector_sgl
 from .fenchel import shrink, sgl_penalty, weighted_l1
-from .groups import GroupSpec, group_norms
+from .groups import GroupSpec, group_norms, pad_slots
 from .lambda_max import dual_scaling_sgl, lambda_max_sgl
 from .linalg import (column_norms, group_frobenius_norms,
-                     group_spectral_norms, spectral_norm)
+                     group_spectral_norms, mm, spectral_norm)
 from .losses import SQUARED, Loss, get_loss
 from .path import PathResult, _bucket, default_lambda_grid
 from .screening import (gap_safe_grid_radii, gap_safe_grid_radii_loss,
@@ -91,7 +91,11 @@ class EngineStats:
     shapes — actual solver compilations; the O(log p) claim is about this
     number.  ``n_rejected`` counts speculative rows whose certificate
     failed (at most one solved row per segment is wasted; the rest are
-    skipped on device).  ``n_pallas_screens`` counts grid screens that ran
+    skipped on device).  ``n_uncertified`` counts rows returned WITHOUT a
+    full-problem certificate: a segment's row 0 whose gap stayed above
+    ``tol`` (its solve hit ``max_iter``); the engine keeps the best iterate
+    so the path can go on, and the row is not certified.
+    ``n_pallas_screens`` counts grid screens that ran
     through the fused Pallas kernels (always 0 on float64 paths — the
     kernels are float32 and ``_pallas_active`` never engages them there).
     ``fold_sweeps`` (fold drivers only) is a per-fold count of sweep
@@ -102,6 +106,7 @@ class EngineStats:
     n_screens: int = 0
     n_compilations: int = 0
     n_rejected: int = 0
+    n_uncertified: int = 0
     n_pallas_screens: int = 0
     buckets: list = dataclasses.field(default_factory=list)  # (p_b, g_b, m, k)
     fold_sweeps: object = None   # (K,) launch counts from the last fold run
@@ -116,6 +121,7 @@ class EngineStats:
         self.n_screens += other.n_screens
         self.n_compilations += other.n_compilations
         self.n_rejected += other.n_rejected
+        self.n_uncertified += other.n_uncertified
         self.n_pallas_screens += other.n_pallas_screens
         if buckets:
             self.buckets.extend(other.buckets)
@@ -134,23 +140,23 @@ def _xtv(X, v, use_pallas: bool):
     if use_pallas:
         from ..kernels import ops as _kops
         return _kops.xtv(X, v)
-    return X.T @ v
+    return mm(X.T, v)
 
 
 def _padded_prox(spec: GroupSpec):
-    """Fused SGL prox through the Pallas kernel on the padded layout.
+    """Fused SGL prox through the Pallas kernel on the slot-major layout.
 
     Padding columns beyond the garbage bin's first ``n_max`` slots never
     enter the padded view; their gradient is zero and they start at zero, so
     scattering back onto a zero vector is exact."""
     from ..kernels import ops as _kops
+    idx, mask = spec.pad_index.T, spec.pad_mask.T
 
     def prox(v, t_l1, t_group):
-        v_pad = jnp.where(spec.pad_mask, v[spec.pad_index], 0.0)
-        out = _kops.sgl_prox_padded(v_pad.astype(jnp.float32), spec.pad_mask,
-                                    t_l1, t_group)
-        return jnp.zeros_like(v).at[spec.pad_index].add(
-            jnp.where(spec.pad_mask, out, 0.0).astype(v.dtype))
+        out = _kops.sgl_prox_padded(pad_slots(spec, v.astype(jnp.float32)),
+                                    mask, t_l1, t_group)
+        return jnp.zeros_like(v).at[idx].add(
+            jnp.where(mask, out, 0.0).astype(v.dtype))
 
     return prox
 
@@ -174,7 +180,8 @@ _gap_safe_radii_jit = jax.jit(gap_safe_grid_radii)
 # static positional (one retrace per loss, not per call)
 _gap_safe_radii_loss_jit = functools.partial(
     jax.jit, static_argnums=(0,))(gap_safe_grid_radii_loss)
-_dpc_grid_jit = jax.jit(dpc_screen_grid)
+_dpc_grid_jit = functools.partial(jax.jit, static_argnames=("use_pallas",))(
+    dpc_screen_grid)
 _gap_safe_nn_jit = jax.jit(gap_safe_screen_grid_nn)
 
 # Feature-sharded grid screens: the executor (``FeatureOps``) is static —
@@ -285,13 +292,13 @@ def sweep_sgl_core(X, X_sub, y, spec: GroupSpec, sub_spec: GroupSpec, alpha,
 
     def step(carry, xs):
         beta, alive = carry
-        lam, ok, idx = xs
+        lam, ok = xs
 
         def run(b):
             res = fista_sgl(X_sub, y, sub_spec, lam, alpha, lipschitz, b,
                             max_iter=max_iter, check_every=check_every,
                             tol=tol, prox=prox, loss=loss)
-            fit = X_sub @ res.beta
+            fit = mm(X_sub, res.beta)
             resid = loss.residual(y, fit)
             rho = resid / lam
             c = _xtv(X, rho, use_pallas).astype(b.dtype)   # full-X GEMV
@@ -303,10 +310,7 @@ def sweep_sgl_core(X, X_sub, y, spec: GroupSpec, sub_spec: GroupSpec, alpha,
             pval = loss.primal_value(y, fit, resid) + lam * pen
             dval = loss.dual_value(y, theta, lam)
             gap = pval - dval
-            # a max_iter-capped solve only certifies on the provably safe
-            # row 0 (legacy accepts its best-effort solution there too)
-            good = (gap <= tol * gap_scale * 1.01) | \
-                   ((idx == 0) & (res.iters >= max_iter))
+            good = gap <= tol * gap_scale * 1.01
             return res.beta, theta, (s * c).astype(b.dtype), good, res.iters
 
         def skip(b):
@@ -317,9 +321,8 @@ def sweep_sgl_core(X, X_sub, y, spec: GroupSpec, sub_spec: GroupSpec, alpha,
             alive & ok, run, skip, beta)
         return (beta_new, alive & good), (beta_new, theta, ctheta, good, its)
 
-    idxs = jnp.arange(lams.shape[0])
     _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
-                          (lams, valid, idxs))
+                          (lams, valid))
     return out   # (betas, thetas, cthetas, good, iters)
 
 
@@ -338,13 +341,13 @@ def sweep_nn_core(X, X_sub, y, lipschitz, lams, valid, beta0, tol,
 
     def step(carry, xs):
         beta, alive = carry
-        lam, ok, idx = xs
+        lam, ok = xs
 
         def run(b):
             res = fista_nn_lasso(X_sub, y, lam, lipschitz, b,
                                  max_iter=max_iter, check_every=check_every,
                                  tol=tol)
-            resid = y - X_sub @ res.beta
+            resid = y - mm(X_sub, res.beta)
             rho = resid / lam
             c = _xtv(X, rho, use_pallas).astype(b.dtype)
             s = dual_scaling_nn(c)
@@ -353,8 +356,7 @@ def sweep_nn_core(X, X_sub, y, lipschitz, lams, valid, beta0, tol,
             d = y - lam * theta
             dval = 0.5 * jnp.vdot(y, y) - 0.5 * jnp.vdot(d, d)
             gap = pval - dval
-            good = (gap <= tol * gap_scale * 1.01) | \
-                   ((idx == 0) & (res.iters >= max_iter))
+            good = gap <= tol * gap_scale * 1.01
             return res.beta, theta, (s * c).astype(b.dtype), good, res.iters
 
         def skip(b):
@@ -365,9 +367,8 @@ def sweep_nn_core(X, X_sub, y, lipschitz, lams, valid, beta0, tol,
             alive & ok, run, skip, beta)
         return (beta_new, alive & good), (beta_new, theta, ctheta, good, its)
 
-    idxs = jnp.arange(lams.shape[0])
     _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
-                          (lams, valid, idxs))
+                          (lams, valid))
     return out
 
 
@@ -397,13 +398,13 @@ def sweep_sgl_core_feat(Xs, X_sub, y, specs, sub_spec: GroupSpec, alpha,
 
     def step(carry, xs):
         beta, alive = carry
-        lam, ok, idx = xs
+        lam, ok = xs
 
         def run(b):
             res = fista_sgl(X_sub, y, sub_spec, lam, alpha, lipschitz, b,
                             max_iter=max_iter, check_every=check_every,
                             tol=tol, prox=None)
-            resid = y - X_sub @ res.beta
+            resid = y - mm(X_sub, res.beta)
             rho = resid / lam
             c_s, s = cert_sgl(ops, Xs, specs, rho, alpha)
             c_s = c_s.astype(b.dtype)
@@ -415,8 +416,7 @@ def sweep_sgl_core_feat(Xs, X_sub, y, specs, sub_spec: GroupSpec, alpha,
             d = y - lam * theta
             dval = 0.5 * jnp.vdot(y, y) - 0.5 * jnp.vdot(d, d)
             gap = pval - dval
-            good = (gap <= tol * gap_scale * 1.01) | \
-                   ((idx == 0) & (res.iters >= max_iter))
+            good = gap <= tol * gap_scale * 1.01
             return (res.beta, theta, (s * c_s).astype(b.dtype), good,
                     res.iters)
 
@@ -429,9 +429,8 @@ def sweep_sgl_core_feat(Xs, X_sub, y, specs, sub_spec: GroupSpec, alpha,
             alive & ok, run, skip, beta)
         return (beta_new, alive & good), (beta_new, theta, ctheta, good, its)
 
-    idxs = jnp.arange(lams.shape[0])
     _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
-                          (lams, valid, idxs))
+                          (lams, valid))
     return out   # (betas, thetas, cthetas (m, S, p_shard), good, iters)
 
 
@@ -444,13 +443,13 @@ def sweep_nn_core_feat(Xs, X_sub, y, lipschitz, lams, valid, beta0, tol,
 
     def step(carry, xs):
         beta, alive = carry
-        lam, ok, idx = xs
+        lam, ok = xs
 
         def run(b):
             res = fista_nn_lasso(X_sub, y, lam, lipschitz, b,
                                  max_iter=max_iter, check_every=check_every,
                                  tol=tol)
-            resid = y - X_sub @ res.beta
+            resid = y - mm(X_sub, res.beta)
             rho = resid / lam
             c_s, s = cert_nn(ops, Xs, rho)
             c_s = c_s.astype(b.dtype)
@@ -459,8 +458,7 @@ def sweep_nn_core_feat(Xs, X_sub, y, lipschitz, lams, valid, beta0, tol,
             d = y - lam * theta
             dval = 0.5 * jnp.vdot(y, y) - 0.5 * jnp.vdot(d, d)
             gap = pval - dval
-            good = (gap <= tol * gap_scale * 1.01) | \
-                   ((idx == 0) & (res.iters >= max_iter))
+            good = gap <= tol * gap_scale * 1.01
             return (res.beta, theta, (s * c_s).astype(b.dtype), good,
                     res.iters)
 
@@ -473,9 +471,8 @@ def sweep_nn_core_feat(Xs, X_sub, y, lipschitz, lams, valid, beta0, tol,
             alive & ok, run, skip, beta)
         return (beta_new, alive & good), (beta_new, theta, ctheta, good, its)
 
-    idxs = jnp.arange(lams.shape[0])
     _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
-                          (lams, valid, idxs))
+                          (lams, valid))
     return out
 
 
@@ -571,7 +568,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     if fshard is not None:
         fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
         fops = _fs.feature_ops(fshard.n_shards, fmesh)
-        Xs = jnp.asarray(fshard.stack_columns(np.asarray(X)))
+        Xs = fops.place(fshard.stack_columns(np.asarray(X)))
         specs_s = fshard.specs_stacked
         xty_s = _fs.sharded_xtv(fops, Xs, y)
         xty_np = fshard.unshard_features(np.asarray(xty_s))
@@ -597,7 +594,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
         # -grad of the loss at beta = 0; y itself for squared loss, so the
         # squared setup GEMV X.T @ y is unchanged
         r0 = loss.residual_at_zero(y)
-        xty = X.T @ r0
+        xty = mm(X.T, r0)
         lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
         lam_max = float(lam_max)
         col_n = column_norms(X)
@@ -678,7 +675,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
         elif not squared:
             # non-squared losses have no Theorem-12 ball; the Gap-Safe
             # ball around the latest certified dual is the only safe rule
-            fit = X @ beta_dev
+            fit = mm(X, beta_dev)
             resid = loss.residual(y, fit)
             pen = (alpha * jnp.sum(spec.weights *
                                    group_norms(spec, beta_dev))
@@ -698,7 +695,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
             if screen == "gapsafe":
                 # both balls certify the dual optimum, so their
                 # intersection screens strictly harder than either alone
-                resid = y - X @ beta_dev
+                resid = y - mm(X, beta_dev)
                 pen = (alpha * jnp.sum(spec.weights *
                                        group_norms(spec, beta_dev))
                        + weighted_l1(spec, beta_dev))
@@ -796,9 +793,10 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
         good_np = np.asarray(good_b[:m])     # one host sync
         k = int(np.argmin(good_np)) if not good_np.all() else m
         if k == 0:
-            # cannot happen for a converged row 0 (its set is provably
-            # safe); belt-and-braces progress guarantee
+            # row 0 (solved on a provably safe set) stopped at max_iter:
+            # keep its best iterate so the path progresses, flagged
             k = 1
+            stats.n_uncertified += 1
         stats.n_rejected += int(m - k)
         theta_bar = thetas_b[k - 1]
         if fshard is not None:
@@ -867,7 +865,7 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
     if fshard is not None:
         fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
         fops = _fs.feature_ops(fshard.n_shards, fmesh)
-        Xs = jnp.asarray(fshard.stack_columns(np.asarray(X)))
+        Xs = fops.place(fshard.stack_columns(np.asarray(X)))
         xty_s = _fs.sharded_xtv(fops, Xs, y)
         xty_np = fshard.unshard_features(np.asarray(xty_s))
         xty = jnp.asarray(xty_np)
@@ -879,7 +877,7 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
         L_full = None
         jax.block_until_ready((col_n_s, x_star))
     else:
-        xty = X.T @ y
+        xty = mm(X.T, y)
         lam_max, i_star = lambda_max_nn(xty)
         lam_max = float(lam_max)
         col_n = column_norms(X)
@@ -945,15 +943,16 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
             n_vec = normal_vector_nn(X, y, lam_bar, lam_max, theta_bar,
                                      i_star)
             fk, _ = _dpc_grid_jit(X, y, rem, theta_bar, n_vec, col_n,
-                                  safety=safety)
+                                  safety=safety, use_pallas=pallas)
             if screen == "gapsafe":
-                resid = y - X @ beta_dev
+                resid = y - mm(X, beta_dev)
                 pen = jnp.sum(beta_dev)          # beta >= 0 => l1 = sum
                 radii = _gap_safe_radii_jit(y, rem, theta_bar, resid,
                                             pen) * (1.0 + safety)
                 fk = fk & _gap_safe_nn_jit(c_prev, radii, col_n)
             fk_np = np.asarray(fk)[:L_rem]
             stats.n_screens += 1
+            stats.n_pallas_screens += int(pallas)
         screen_time += time.perf_counter() - ts
 
         row_counts = fk_np.sum(axis=1)
@@ -1026,6 +1025,7 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
         k = int(np.argmin(good_np)) if not good_np.all() else m
         if k == 0:
             k = 1
+            stats.n_uncertified += 1
         stats.n_rejected += int(m - k)
         theta_bar = thetas_b[k - 1]
         if fshard is not None:

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from .estimation import DualBall, project_out_normal
 from .fenchel import shrink
 from .groups import (GroupSpec, broadcast_to_features, group_max_abs,
-                     group_norms)
+                     group_norms, pad_slots)
+from .linalg import mm
 
 
 @jax.tree_util.register_pytree_node_class
@@ -58,7 +59,7 @@ def tlfre_screen(X, spec: GroupSpec, alpha, ball: DualBall,
     a few ULPs when running in float32; exactness tests use 0 under float64).
     """
     r = ball.radius * (1.0 + safety)
-    c = X.T @ ball.center                       # (p,)  — the screening GEMV
+    c = mm(X.T, ball.center)                    # (p,)  — the screening GEMV
     if spec.feature_weights is None:
         shr = shrink(c)
         c_norm = group_norms(spec, shr)
@@ -105,17 +106,15 @@ def _grid_group_stats(spec: GroupSpec, C: jnp.ndarray, use_pallas: bool):
     """(||S_1(C_g)||, ||C_g||_inf) per grid row: (L, p) -> ((L, G), (L, G)).
 
     ``use_pallas`` routes the fused reduction through the ``screen_norms``
-    kernel on the padded (L*G, n_max) layout (float32 — callers must carry a
-    nonzero ``safety`` inflation; the float64 exactness path keeps the jnp
-    segment reductions and float64 inputs refuse the kernel route).
+    kernel on the slot-major (n_max, L, G) layout (float32 — callers must
+    carry a nonzero ``safety`` inflation; the float64 exactness path keeps
+    the jnp segment reductions and float64 inputs refuse the kernel route).
     """
     if use_pallas:
         _require_f32_for_pallas(C.dtype)
         from ..kernels import ops as _kops
-        L = C.shape[0]
-        c_pad = jnp.where(spec.pad_mask[None], C[:, spec.pad_index], 0.0)
         snorm2, cinf = _kops.screen_norms_batched(
-            c_pad.astype(jnp.float32), spec.pad_mask)
+            pad_slots(spec, C.astype(jnp.float32)), spec.pad_mask.T)
         return jnp.sqrt(snorm2).astype(C.dtype), cinf.astype(C.dtype)
     c_norm = jax.vmap(lambda r: group_norms(spec, r))(shrink(C))   # (L, G)
     c_inf = jax.vmap(lambda r: group_max_abs(spec, r))(jnp.abs(C))
@@ -133,10 +132,8 @@ def _grid_group_stats_folds(spec: GroupSpec, C: jnp.ndarray,
     if use_pallas:
         _require_f32_for_pallas(C.dtype)
         from ..kernels import ops as _kops
-        c_pad = jnp.where(spec.pad_mask[None, None],
-                          C[:, :, spec.pad_index], 0.0)
         snorm2, cinf = _kops.screen_norms_folds(
-            c_pad.astype(jnp.float32), spec.pad_mask)
+            pad_slots(spec, C.astype(jnp.float32)), spec.pad_mask.T)
         return jnp.sqrt(snorm2).astype(C.dtype), cinf.astype(C.dtype)
     return jax.vmap(lambda Ck: _grid_group_stats(spec, Ck, False))(C)
 
@@ -197,7 +194,7 @@ def tlfre_screen_grid(X, y, spec: GroupSpec, alpha, lambdas, lam_bar,
     """
     centers, radii = grid_ball_geometry(y, lambdas, theta_bar, n_vec)
     radii = radii * (1.0 + safety)
-    C = centers @ X                                                # (L, p)
+    C = mm(centers, X)                                             # (L, p)
     group_keep, feat_keep = _grid_rules(spec, alpha, C, radii, col_norms,
                                         group_specnorms, use_pallas)
     return group_keep, feat_keep, radii
@@ -264,7 +261,7 @@ def tlfre_screen_grid_folds(X, Y, spec: GroupSpec, alpha, lambdas, Theta_bar,
     N = Y.shape[1]
     centers, radii = grid_ball_geometry_folds(Y, lambdas, Theta_bar, N_vecs)
     radii = radii * (1.0 + safety)
-    C = (centers.reshape(K * L, N) @ X).reshape(K, L, X.shape[1])
+    C = mm(centers.reshape(K * L, N), X).reshape(K, L, X.shape[1])
     if mus is not None:
         C = C - centers.sum(axis=2)[:, :, None] * mus[:, None, :]
     group_keep, feat_keep = _grid_rules_folds(spec, alpha, C, radii,
@@ -345,7 +342,7 @@ def tlfre_screen_grid_feat(ops, Xs, specs, y, alpha, lambdas, theta_bar,
 
     def body(loc, centers, radii, alpha):
         Xb, spec_loc, cn, gs = loc
-        C = centers @ Xb
+        C = mm(centers, Xb)
         return _grid_rules(spec_loc, alpha, C, radii, cn, gs, False)
 
     group_keep_s, feat_keep_s = ops.fmap(
@@ -386,7 +383,8 @@ def tlfre_screen_grid_folds_feat(ops, Xs, specs, Y, alpha, lambdas,
     if mus_s is None:
         def body(loc, centers, radii, alpha):
             Xb, spec_loc, cn, gs = loc
-            C = (centers.reshape(K * L, N) @ Xb).reshape(K, L, Xb.shape[1])
+            C = mm(centers.reshape(K * L, N), Xb).reshape(
+                K, L, Xb.shape[1])
             return _grid_rules_folds(spec_loc, alpha, C, radii, cn, gs,
                                      False)
 
@@ -396,7 +394,8 @@ def tlfre_screen_grid_folds_feat(ops, Xs, specs, Y, alpha, lambdas,
     else:
         def body(loc, centers, csum, radii, alpha):
             Xb, spec_loc, cn, gs, mu = loc
-            C = (centers.reshape(K * L, N) @ Xb).reshape(K, L, Xb.shape[1])
+            C = mm(centers.reshape(K * L, N), Xb).reshape(
+                K, L, Xb.shape[1])
             C = C - csum[:, :, None] * mu[:, None, :]
             return _grid_rules_folds(spec_loc, alpha, C, radii, cn, gs,
                                      False)

@@ -53,6 +53,7 @@ from .cv import (CVResult, EngineStats, FoldState, StabilityResult,
 from .dpc import dual_scaling_nn, lambda_max_nn
 from .groups import GroupSpec
 from .lambda_max import dual_scaling_sgl, lambda_max_sgl
+from .linalg import mm
 from .losses import get_loss
 from .path_engine import (nn_lasso_path_batched, sgl_path_batched)
 from .problem import Plan, Problem
@@ -83,17 +84,17 @@ def _fold_duals_sgl(X, spec, alpha, Y, masks, betas, lam_ref, mus):
     scaling of the (masked, centered) residual recovers each fold's exact
     dual there — the same algebra the engine's in-scan certification uses.
     """
-    fit = betas @ X.T
+    fit = mm(betas, X.T)
     if mus is not None:
         fit = fit - jnp.sum(betas * mus, axis=1)[:, None]
     resid = Y - masks * fit
     rho = resid / lam_ref
-    c = rho @ X
+    c = mm(rho, X)
     if mus is not None:
         c = c - jnp.sum(rho, axis=1)[:, None] * mus
-        xty = Y @ X - jnp.sum(Y, axis=1)[:, None] * mus
+        xty = mm(Y, X) - jnp.sum(Y, axis=1)[:, None] * mus
     else:
-        xty = Y @ X
+        xty = mm(Y, X)
     s = jax.vmap(lambda ck: dual_scaling_sgl(spec, ck, alpha))(c)
     lam_max_f, _ = jax.vmap(lambda ck: lambda_max_sgl(spec, ck, alpha))(xty)
     return s[:, None] * rho, s[:, None] * c, xty, lam_max_f
@@ -101,10 +102,10 @@ def _fold_duals_sgl(X, spec, alpha, Y, masks, betas, lam_ref, mus):
 
 @jax.jit
 def _fold_duals_nn(X, Y, masks, betas, lam_ref):
-    resid = Y - masks * (betas @ X.T)
+    resid = Y - masks * mm(betas, X.T)
     rho = resid / lam_ref
-    c = rho @ X
-    xty = Y @ X
+    c = mm(rho, X)
+    xty = mm(Y, X)
     s = jax.vmap(dual_scaling_nn)(c)
     lam_max_f, _ = jax.vmap(lambda_max_nn)(xty)
     return s[:, None] * rho, s[:, None] * c, xty, lam_max_f
@@ -141,12 +142,12 @@ class SGLSession:
         self.stats = EngineStats()       # aggregate over the session
         self._lam_max_cache: dict = {}   # grid-anchor cache (see lambda_max)
         if problem.loss == "squared":
-            self._xty = problem.X.T @ problem.y
+            self._xty = mm(problem.X.T, problem.y)
         else:
             # the grid anchor correlates X with the gradient of the loss at
             # beta = 0 (y for squared; y - 1/2 for logistic)
-            self._xty = problem.X.T @ get_loss(problem.loss).residual_at_zero(
-                problem.y)
+            self._xty = mm(problem.X.T, get_loss(
+                problem.loss).residual_at_zero(problem.y))
         self._last_cv: Optional[_CVState] = None
 
     # ---- plumbing ---------------------------------------------------------

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from .fenchel import sgl_penalty
 from .groups import GroupSpec
 from .lambda_max import dual_scaling_sgl
+from .linalg import mm
 from .losses import SQUARED, Loss
 from .prox import nn_lasso_prox, sgl_prox
 from . import dpc as _dpc
@@ -33,16 +34,34 @@ class SolveResult(NamedTuple):
     iters: jnp.ndarray
 
 
+def _restart(z, beta_new, beta):
+    """O'Donoghue-Candes adaptive restart: reset the momentum when the
+    extrapolated direction opposes progress, ``<z - b', b' - b> > 0``.
+
+    Both differences carry about ``eps * |b|`` of rounding each, so near
+    convergence a bare ``> 0`` test is a coin flip.  In float32 it then kills
+    the momentum every other step and FISTA stalls at ISTA's rate short of a
+    1e-5 certificate (image-dictionary nonnegative Lasso: rows capped at
+    ``max_iter`` that converge in ~2k iterations once the test ignores
+    noise).  The test asks for more than 64 ulps of that rounding (the
+    certificate floor's margin, ``Loss.effective_tol``); in float64 the
+    margin sits far below any step the solver takes."""
+    d, step = z - beta_new, beta_new - beta
+    noise = (64.0 * jnp.finfo(beta.dtype).eps * jnp.linalg.norm(beta_new)
+             * (jnp.linalg.norm(d) + jnp.linalg.norm(step)))
+    return jnp.vdot(d, step) > noise
+
+
 # ---------------------------------------------------------------------------
 # SGL
 # ---------------------------------------------------------------------------
 
 def _sgl_gap(X, y, spec, lam, alpha, beta, loss: Loss = SQUARED):
     """(primal, dual, theta_feasible) at beta."""
-    fit = X @ beta
+    fit = mm(X, beta)
     resid = loss.residual(y, fit)
     rho = resid / lam
-    s = dual_scaling_sgl(spec, X.T @ rho, alpha)
+    s = dual_scaling_sgl(spec, mm(X.T, rho), alpha)
     theta = s * rho
     p = loss.primal_value(y, fit, resid) + lam * sgl_penalty(spec, beta, alpha)
     d = loss.dual_value(y, theta, lam)
@@ -81,7 +100,7 @@ def fista_sgl(X, y, spec: GroupSpec, lam, alpha, lipschitz, beta0, *,
         prox = lambda v, a, b: sgl_prox(spec, v, a, b)
 
     def prox_grad(z):
-        g = X.T @ loss.grad(y, X @ z)
+        g = mm(X.T, loss.grad(y, mm(X, z)))
         # spec.weights is float64 for exactness; pin the iterate dtype so
         # float32 problems under jax_enable_x64 keep a stable carry
         return prox(z - t_step * g, t_l1, t_group).astype(dtype)
@@ -89,10 +108,7 @@ def fista_sgl(X, y, spec: GroupSpec, lam, alpha, lipschitz, beta0, *,
     def inner(carry, _):
         beta, z, tk = carry
         beta_new = prox_grad(z)
-        # O'Donoghue-Candes adaptive restart: reset momentum when the
-        # extrapolated direction opposes progress
-        restart = jnp.vdot(z - beta_new, beta_new - beta) > 0
-        tk = jnp.where(restart, 1.0, tk)
+        tk = jnp.where(_restart(z, beta_new, beta), 1.0, tk)
         tk1 = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * tk * tk))
         z_new = beta_new + ((tk - 1.0) / tk1) * (beta_new - beta)
         return (beta_new, z_new, tk1), None
@@ -133,8 +149,8 @@ def solve_sgl(X, y, spec: GroupSpec, lam, alpha, lipschitz, beta0=None, *,
 # ---------------------------------------------------------------------------
 
 def _nn_gap(X, y, lam, beta):
-    rho = (y - X @ beta) / lam
-    s = _dpc.dual_scaling_nn(X.T @ rho)
+    rho = (y - mm(X, beta)) / lam
+    s = _dpc.dual_scaling_nn(mm(X.T, rho))
     theta = s * rho
     p = _dpc.nn_primal_objective(X, y, beta, lam)
     d = _dpc.nn_dual_objective(y, theta, lam)
@@ -152,10 +168,9 @@ def fista_nn_lasso(X, y, lam, lipschitz, beta0, *, max_iter: int = 20000,
 
     def inner(carry, _):
         beta, z, tk = carry
-        g = X.T @ (X @ z - y)
+        g = mm(X.T, mm(X, z) - y)
         beta_new = nn_lasso_prox(z - t_step * g, t_step * lam)
-        restart = jnp.vdot(z - beta_new, beta_new - beta) > 0
-        tk = jnp.where(restart, 1.0, tk)
+        tk = jnp.where(_restart(z, beta_new, beta), 1.0, tk)
         tk1 = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * tk * tk))
         z_new = beta_new + ((tk - 1.0) / tk1) * (beta_new - beta)
         return (beta_new, z_new, tk1), None

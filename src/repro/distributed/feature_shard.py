@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from .sharding import divisible
 from ..core.groups import GroupSpec
+from ..core.linalg import mm
 
 
 def effective_shards(n_units: int, requested: int) -> int:
@@ -225,13 +226,21 @@ class FeatureOps:
     mesh: object = None
 
     def _shard_map(self, wrapped, n_rep, reduce_out):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         out_specs = P() if reduce_out else P("feature")
-        return shard_map(
+        return jax.shard_map(
             wrapped, mesh=self.mesh,
             in_specs=(P("feature"),) + (P(),) * n_rep,
-            out_specs=out_specs, check_rep=False)
+            out_specs=out_specs, check_vma=False)
+
+    def place(self, x):
+        """Put a stacked ``(S, ...)`` host array where the executor reads
+        it: one block per device of the 'feature' mesh, or one device for
+        the vmap executor."""
+        if self.mesh is None:
+            return jnp.asarray(x)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(x, NamedSharding(self.mesh, P("feature")))
 
     def fmap(self, body, sharded, *replicated):
         """``body(local_block, *replicated) -> local_out`` mapped over the
@@ -291,7 +300,7 @@ def resolve_feature_mesh(n_shards: int):
 
 def sharded_xtv(ops: FeatureOps, Xs, v):
     """Stacked correlations ``(S, p_shard)``: each shard's ``X_blk^T v``."""
-    return ops.fmap(lambda Xb, vv: Xb.T @ vv, Xs, v)
+    return ops.fmap(lambda Xb, vv: mm(Xb.T, vv), Xs, v)
 
 
 def sharded_fit(ops: FeatureOps, Xs, v_s):
@@ -300,7 +309,7 @@ def sharded_fit(ops: FeatureOps, Xs, v_s):
     shard + psum; pad columns multiply zero coefficients."""
     def body(loc):
         Xb, vb = loc
-        return vb @ Xb.T if vb.ndim > 1 else Xb @ vb
+        return mm(vb, Xb.T) if vb.ndim > 1 else mm(Xb, vb)
     return ops.fsum(body, (Xs, v_s))
 
 
@@ -343,7 +352,7 @@ def sharded_spectral_norm(ops: FeatureOps, Xs, col_mask_s, iters: int = 50,
 
     def step(_, v):
         u = sharded_fit(ops, Xs, v)
-        w = ops.fmap(lambda Xb, uu: Xb.T @ uu, Xs, u)
+        w = ops.fmap(lambda Xb, uu: mm(Xb.T, uu), Xs, u)
         return w / jnp.maximum(jnp.sqrt(jnp.sum(w * w)), 1e-30)
 
     v = jax.lax.fori_loop(0, iters, step, v)
@@ -361,7 +370,7 @@ def cert_sgl(ops: FeatureOps, Xs, specs, rho, alpha):
 
     def body(loc, rho, alpha):
         Xb, spec_loc = loc
-        c = Xb.T @ rho
+        c = mm(Xb.T, rho)
         return c, group_shrink_roots(spec_loc, c, alpha)
 
     c_s, roots = ops.fmap(body, (Xs, specs), rho, jnp.asarray(alpha))

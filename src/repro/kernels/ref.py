@@ -2,6 +2,8 @@
 
 These are the semantics the kernels must reproduce; tests assert_allclose
 kernels (interpret=True on CPU) against these for swept shapes/dtypes.
+Group statistics use the slot-major ``(n_max, ..., G)`` layout of
+``core.groups.pad_slots``.
 """
 from __future__ import annotations
 
@@ -14,30 +16,36 @@ def xtv_ref(X: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
                       preferred_element_type=jnp.float32)
 
 
-def screen_norms_ref(c_pad: jnp.ndarray, mask: jnp.ndarray):
-    """Fused screening statistics over the padded group layout.
+def _slot_mask(mask, ndim):
+    """(n_max, G) mask broadcast against an (n_max, ..., G) operand."""
+    return mask.reshape(mask.shape[:1] + (1,) * (ndim - 2) + mask.shape[1:])
 
-    c_pad: (G, n_max), mask: (G, n_max) bool.
-    Returns (||S_1(c_g)||^2, ||c_g||_inf) each of shape (G,), float32.
+
+def screen_norms_ref(c_slots: jnp.ndarray, mask: jnp.ndarray):
+    """Fused screening statistics over the slot-major padded layout.
+
+    c_slots: (n_max, ..., G), mask: (n_max, G) bool.
+    Returns (||S_1(c_g)||^2, ||c_g||_inf), each of shape (..., G), float32.
     """
-    c = jnp.where(mask, c_pad.astype(jnp.float32), 0.0)
+    c = jnp.where(_slot_mask(mask, c_slots.ndim), c_slots.astype(jnp.float32),
+                  0.0)
     sh = jnp.sign(c) * jnp.maximum(jnp.abs(c) - 1.0, 0.0)
-    snorm2 = jnp.sum(sh * sh, axis=1)
-    cinf = jnp.max(jnp.abs(c), axis=1)
+    snorm2 = jnp.sum(sh * sh, axis=0)
+    cinf = jnp.max(jnp.abs(c), axis=0)
     return snorm2, cinf
 
 
-def sgl_prox_ref(v_pad: jnp.ndarray, mask: jnp.ndarray, t_l1: jnp.ndarray,
+def sgl_prox_ref(v_slots: jnp.ndarray, mask: jnp.ndarray, t_l1: jnp.ndarray,
                  t_group: jnp.ndarray) -> jnp.ndarray:
-    """Fused SGL prox on the padded layout.
+    """Fused SGL prox on the slot-major layout.
 
-    v_pad: (G, n_max), mask: (G, n_max), t_l1 scalar, t_group: (G,).
+    v_slots: (n_max, G), mask: (n_max, G), t_l1 scalar, t_group: (G,).
     Returns the padded prox output (invalid slots zero), float32.
     """
-    v = jnp.where(mask, v_pad.astype(jnp.float32), 0.0)
+    v = jnp.where(mask, v_slots.astype(jnp.float32), 0.0)
     u = jnp.sign(v) * jnp.maximum(jnp.abs(v) - t_l1, 0.0)
-    norms = jnp.sqrt(jnp.sum(u * u, axis=1))
+    norms = jnp.sqrt(jnp.sum(u * u, axis=0))
     tg = t_group.astype(jnp.float32)
     scale = jnp.where(norms > tg, 1.0 - tg / jnp.where(norms > 0, norms, 1.0),
                       0.0)
-    return u * scale[:, None]
+    return u * scale[None, :]

@@ -1,143 +1,122 @@
-"""Pallas TPU kernel: fused screening statistics.
+"""Pallas TPU kernels: fused screening statistics.
 
-After the GEMV c = X^T o, TLFre needs per group g:
-    ||S_1(c_g)||^2   (Theorem 15 branch 1)
-    ||c_g||_inf      (Theorem 15 branch selection + branch 2)
-and per feature |c_i| (Theorem 16 — already available as |c|).
+After the screening GEMM C = centers @ X, TLFre needs per (row l, group g):
+    ||S_1(c_lg)||^2   (Theorem 15 branch 1)
+    ||c_lg||_inf      (Theorem 15 branch selection + branch 2)
+and per feature |c_i| (Theorem 16 — already available as |C|).
 
-A naive jnp implementation reads the p-length vector from HBM three times
-(shrink, square-reduce, max-reduce).  This kernel fuses all of it into ONE
-streaming pass over the padded (G, n_max) layout: each grid step loads a
-(BG, n_max) tile into VMEM, applies the mask, and writes the two (BG, 1)
-statistics.  n_max is padded to a multiple of 128 lanes.
+A naive jnp implementation reads C from HBM three times (shrink,
+square-reduce, max-reduce).  ``screen_norms_pallas`` fuses it into ONE
+streaming pass over the **slot-major** padded layout ``(n_max, L, G)``:
+slot s of group g sits at ``[s, :, g]``.  Groups lie on lanes and grid rows
+on sublanes, so the only padding is ragged groups up to ``n_max`` slots (an
+untiled leading axis): the operand holds ``n_max * G / p`` times the bytes
+of C.  The group-major ``(L, G, n_max)`` layout this replaces put ``n_max``
+on lanes, where TPU tiling pads it to 128 — about 13x the bytes of C for
+gene-sized groups of ~4.5 SNPs.  Each grid step loads an
+``(n_max, BL, BG)`` tile, masks invalid slots, and reduces over the leading
+axis (elementwise adds across vreg tiles) into two ``(BL, BG)`` outputs.
+
+Edge tiles may overhang L and G: every output element depends only on its
+own (l, g) column of slots, so overhang rows/lanes read unspecified values
+and their outputs are dropped by the partial-block write.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 
-DEFAULT_BG = 256
+SUBLANE = 8
+LANE = 128
+# block index 0 of an index map: a Python 0 traces as int64 under
+# jax_enable_x64, which Mosaic refuses to compile
+I0 = np.int32(0)
+# per-operand tile budget: double-buffered inputs stay well inside v5e's
+# 16 MiB scoped VMEM
+TILE_BYTES = 2 * 1024 * 1024
+MAX_BLOCK_G = 2048
+
+
+def _row_block(n: int, want: int = SUBLANE) -> int:
+    """A sublane block: the whole (short) dim, or a multiple of 8."""
+    return n if n <= want else want
+
+
+def _lane_block(n: int, width_bytes: int) -> int:
+    """A lane block sized to ``TILE_BYTES`` for a tile whose other dims
+    hold ``width_bytes`` per lane: the whole dim if it fits, else a
+    multiple of 128."""
+    fit = (TILE_BYTES // max(width_bytes, 1)) // LANE * LANE
+    if fit < LANE:
+        raise ValueError(
+            f"a {width_bytes}-byte lane column does not fit a "
+            f"{TILE_BYTES}-byte VMEM tile; the fused kernel cannot take "
+            f"groups this large")
+    want = min(fit, MAX_BLOCK_G)
+    return n if n <= want else want
 
 
 def _screen_norms_kernel(c_ref, m_ref, s_ref, i_ref):
-    c = jnp.where(m_ref[...], c_ref[...].astype(jnp.float32), 0.0)
-    a = jnp.abs(c)
+    valid = m_ref[...] != 0                               # (n_max, 1, BG)
+    a = jnp.where(valid, jnp.abs(c_ref[...].astype(jnp.float32)), 0.0)
     sh = jnp.maximum(a - 1.0, 0.0)
-    s_ref[...] = jnp.sum(sh * sh, axis=1, keepdims=True)
-    i_ref[...] = jnp.max(a, axis=1, keepdims=True)
+    s_ref[...] = jnp.sum(sh * sh, axis=0)
+    i_ref[...] = jnp.max(a, axis=0)
 
 
-def screen_norms_pallas(c_pad: jnp.ndarray, mask: jnp.ndarray, *,
-                        block_g: int = DEFAULT_BG, interpret: bool = False):
-    """c_pad: (G, n_max), mask: (G, n_max) -> (snorm2 (G,), cinf (G,)) f32."""
-    G, n_max = c_pad.shape
-    Gp = -(-G // block_g) * block_g
-    nl = -(-n_max // 128) * 128
-    cp = jnp.pad(c_pad, ((0, Gp - G), (0, nl - n_max)))
-    mp = jnp.pad(mask, ((0, Gp - G), (0, nl - n_max)))
+def screen_norms_pallas(c_slots: jnp.ndarray, mask: jnp.ndarray, *,
+                        block_l: int = SUBLANE, block_g: int | None = None,
+                        interpret: bool = False):
+    """Fused screening statistics on the slot-major layout.
 
+    ``c_slots``: (n_max, L, G) — every grid row's correlations gathered by
+    ``GroupSpec.pad_index.T``; ``mask``: (n_max, G) validity of the slots
+    (shared by every row).  Returns ``(snorm2 (L, G), cinf (L, G))``
+    float32.  Invalid slots are masked, whatever they hold."""
+    if c_slots.ndim != 3 or mask.shape != (c_slots.shape[0],
+                                           c_slots.shape[2]):
+        raise ValueError(f"expected c_slots (n_max, L, G) and mask "
+                         f"(n_max, G); got {c_slots.shape} / {mask.shape}")
+    n_max, L, G = c_slots.shape
+    bl = _row_block(L, block_l)
+    bg = block_g if block_g is not None else _lane_block(G, n_max * bl * 4)
     snorm2, cinf = pl.pallas_call(
         _screen_norms_kernel,
-        grid=(Gp // block_g,),
+        grid=(pl.cdiv(L, bl), pl.cdiv(G, bg)),
         in_specs=[
-            pl.BlockSpec((block_g, nl), lambda i: (i, 0)),
-            pl.BlockSpec((block_g, nl), lambda i: (i, 0)),
+            pl.BlockSpec((n_max, bl, bg), lambda i, j: (I0, i, j)),
+            pl.BlockSpec((n_max, 1, bg), lambda i, j: (I0, I0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((block_g, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_g, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bl, bg), lambda i, j: (i, j)),
+            pl.BlockSpec((bl, bg), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Gp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((Gp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, G), jnp.float32),
+            jax.ShapeDtypeStruct((L, G), jnp.float32),
         ],
         interpret=interpret,
-    )(cp, mp)
-    return snorm2[:G, 0], cinf[:G, 0]
-
-
-# ---------------------------------------------------------------------------
-# Fold-stacked variant: the (K*L, G, n_max) CV layout
-# ---------------------------------------------------------------------------
-
-DEFAULT_BKL = 8
-DEFAULT_BG_FOLDS = 128
-
-
-def _screen_norms_folds_kernel(c_ref, m_ref, s_ref, i_ref):
-    c = jnp.where(m_ref[...][None], c_ref[...].astype(jnp.float32), 0.0)
-    a = jnp.abs(c)
-    sh = jnp.maximum(a - 1.0, 0.0)
-    s_ref[...] = jnp.sum(sh * sh, axis=2)
-    i_ref[...] = jnp.max(a, axis=2)
-
-
-def screen_norms_folds_pallas(c_pad_kl: jnp.ndarray, mask: jnp.ndarray, *,
-                              block_kl: int = DEFAULT_BKL,
-                              block_g: int = DEFAULT_BG_FOLDS,
-                              interpret: bool = False):
-    """Fold-stacked screening statistics for the CV engine.
-
-    ``c_pad_kl``: (K*L, G, n_max) — every (fold, lambda) pair's correlation
-    vector on the padded group layout; ``mask``: (G, n_max) shared validity
-    mask (all rows see the same GroupSpec).  Returns
-    ``(snorm2 (K*L, G), cinf (K*L, G))`` float32.
-
-    The grid tiles fold-x-lambda rows against group blocks, so one kernel
-    launch streams the whole stacked screen — the reduction half of the
-    ``(K*L, N) x (N, p)`` fold-stack GEMM — with the same padded-lane
-    masking as ``screen_norms_pallas`` (the mask block is indexed by the
-    group tile only and reused across every fold-x-lambda tile).
-    """
-    KL, G, n_max = c_pad_kl.shape
-    KLp = -(-KL // block_kl) * block_kl
-    Gp = -(-G // block_g) * block_g
-    nl = -(-n_max // 128) * 128
-    cp = jnp.pad(c_pad_kl, ((0, KLp - KL), (0, Gp - G), (0, nl - n_max)))
-    mp = jnp.pad(mask, ((0, Gp - G), (0, nl - n_max)))
-
-    snorm2, cinf = pl.pallas_call(
-        _screen_norms_folds_kernel,
-        grid=(KLp // block_kl, Gp // block_g),
-        in_specs=[
-            pl.BlockSpec((block_kl, block_g, nl), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_g, nl), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_kl, block_g), lambda i, j: (i, j)),
-            pl.BlockSpec((block_kl, block_g), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((KLp, Gp), jnp.float32),
-            jax.ShapeDtypeStruct((KLp, Gp), jnp.float32),
-        ],
-        interpret=interpret,
-    )(cp, mp)
-    return snorm2[:KL, :G], cinf[:KL, :G]
+    )(c_slots, mask.astype(jnp.int32)[:, None, :])
+    return snorm2, cinf
 
 
 # ---------------------------------------------------------------------------
 # Fold-stacked DPC rule: fused omega = C + r * ||x_i|| threshold
 # ---------------------------------------------------------------------------
 
-DEFAULT_BL = 8
-DEFAULT_BP = 512
-
-
 def _dpc_screen_folds_kernel(c_ref, r_ref, n_ref, o_ref):
-    c = c_ref[...].astype(jnp.float32)            # (1, bl, bp)
-    r = r_ref[...].astype(jnp.float32)            # (1, bl)
-    cn = n_ref[...].astype(jnp.float32)           # (1, bp)
-    omega = c + r[:, :, None] * cn[:, None, :]
-    o_ref[...] = (omega >= 1.0).astype(jnp.float32)
+    c = c_ref[...].astype(jnp.float32)            # (1, BL, BP)
+    r = r_ref[...].astype(jnp.float32)            # (1, BL, 1)
+    cn = n_ref[...].astype(jnp.float32)           # (1, 1, BP)
+    o_ref[...] = (c + r * cn >= 1.0).astype(jnp.float32)
 
 
 def dpc_screen_folds_pallas(C: jnp.ndarray, radii: jnp.ndarray,
                             col_norms_f: jnp.ndarray, *,
-                            block_l: int = DEFAULT_BL,
-                            block_p: int = DEFAULT_BP,
+                            block_l: int = SUBLANE, block_p: int | None = None,
                             interpret: bool = False):
     """Fused Theorem-22 grid rule on the fold-stacked CV layout.
 
@@ -145,27 +124,26 @@ def dpc_screen_folds_pallas(C: jnp.ndarray, radii: jnp.ndarray,
     design), ``radii``: (K, L) safety-inflated ball radii, ``col_norms_f``:
     (K, p) per-fold masked column norms.  Returns ``feat_keep (K, L, p)``
     bool — one streaming pass instead of materialising omega in HBM.  The
-    grid walks (fold, lambda-tile, feature-tile); the radius and column-norm
-    blocks are broadcast along the feature and lambda axes respectively.
-    """
+    grid walks (fold, lambda-tile, feature-tile); radii ride as a
+    ``(K, L, 1)`` column and the norms as a ``(K, 1, p)`` row, so every
+    block's minor two dims are (8k or full, 128k or full)."""
     K, L, p = C.shape
-    Lp = -(-L // block_l) * block_l
-    pp = -(-p // block_p) * block_p
-    cp = jnp.pad(C, ((0, 0), (0, Lp - L), (0, pp - p)))
-    rp = jnp.pad(radii, ((0, 0), (0, Lp - L)))
-    np_ = jnp.pad(col_norms_f, ((0, 0), (0, pp - p)))
-
+    if radii.shape != (K, L) or col_norms_f.shape != (K, p):
+        raise ValueError(f"expected radii (K, L) and col_norms_f (K, p) for "
+                         f"C {C.shape}; got {radii.shape} / "
+                         f"{col_norms_f.shape}")
+    bl = _row_block(L, block_l)
+    bp = block_p if block_p is not None else _lane_block(p, bl * 4)
     keep = pl.pallas_call(
         _dpc_screen_folds_kernel,
-        grid=(K, Lp // block_l, pp // block_p),
+        grid=(K, pl.cdiv(L, bl), pl.cdiv(p, bp)),
         in_specs=[
-            pl.BlockSpec((1, block_l, block_p), lambda k, i, j: (k, i, j)),
-            pl.BlockSpec((1, block_l), lambda k, i, j: (k, i)),
-            pl.BlockSpec((1, block_p), lambda k, i, j: (k, j)),
+            pl.BlockSpec((1, bl, bp), lambda k, i, j: (k, i, j)),
+            pl.BlockSpec((1, bl, 1), lambda k, i, j: (k, i, I0)),
+            pl.BlockSpec((1, 1, bp), lambda k, i, j: (k, I0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_l, block_p),
-                               lambda k, i, j: (k, i, j)),
-        out_shape=jax.ShapeDtypeStruct((K, Lp, pp), jnp.float32),
+        out_specs=pl.BlockSpec((1, bl, bp), lambda k, i, j: (k, i, j)),
+        out_shape=jax.ShapeDtypeStruct((K, L, p), jnp.float32),
         interpret=interpret,
-    )(cp, rp, np_)
-    return keep[:, :L, :p] > 0.5
+    )(C, radii[:, :, None], col_norms_f[:, None, :])
+    return keep > 0.5

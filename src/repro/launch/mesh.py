@@ -10,12 +10,8 @@ import jax
 
 
 def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; omit it on older releases
-    (jax <= 0.4.x defaults to the same auto behaviour)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """Every mesh axis is ``Auto``: the compiler places the collectives."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -78,10 +74,7 @@ def abstract_feature_mesh(n_shards: int):
     collective plans without multi-device hardware (the Layer-4 audit
     proves the plan is psum-only; see ``abstract_fold_mesh``)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("feature", int(n_shards)),))
-    except TypeError:      # older AbstractMesh signature takes a dict
-        return AbstractMesh({"feature": int(n_shards)})
+    return AbstractMesh((int(n_shards),), ("feature",))
 
 
 def make_fold_feature_mesh(n_folds: int, n_shards: int):
@@ -115,10 +108,7 @@ def abstract_fold_mesh(n_shards: int):
     bodies stay collective-free without ever forcing
     ``xla_force_host_platform_device_count``."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((("fold", int(n_shards)),))
-    except TypeError:      # older AbstractMesh signature takes a dict
-        return AbstractMesh({"fold": int(n_shards)})
+    return AbstractMesh((int(n_shards),), ("fold",))
 
 
 def fold_axis_size(mesh) -> int:
@@ -169,10 +159,9 @@ def shard_over_folds(fn, mesh, example_args):
     (shard_map over one shard adds tracing overhead for nothing)."""
     if mesh is None or fold_axis_size(mesh) == 1:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     specs = tuple(PartitionSpec("fold") if a == 0 else PartitionSpec()
                   for a in example_args)
-    return shard_map(fn, mesh=mesh, in_specs=specs,
-                     out_specs=PartitionSpec("fold"), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                         out_specs=PartitionSpec("fold"), check_vma=False)

@@ -311,6 +311,18 @@ class SGLServer:
         return results
 
 
+def raise_on_errors(results: dict) -> dict:
+    """Return ``results`` if every job succeeded; otherwise raise.
+
+    ``drain`` isolates a failing batch by turning its exception into
+    ``JobResult.error``, so a caller that reports success must check."""
+    errors = {jid: r.error for jid, r in results.items() if r.error}
+    if errors:
+        raise RuntimeError(f"{len(errors)} of {len(results)} jobs failed: "
+                           f"{errors}")
+    return results
+
+
 # ---------------------------------------------------------------------------
 # Smoke CLI
 # ---------------------------------------------------------------------------
@@ -345,6 +357,8 @@ def main(argv=None):
     if not args.smoke:
         ap.error("only --smoke is implemented as a CLI; use SGLServer "
                  "programmatically for real queues")
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     plan = Plan(n_folds=args.folds, n_lambdas=args.lambdas, tol=1e-6,
                 safety=1e-6, max_iter=6000, check_every=50)
@@ -361,11 +375,11 @@ def main(argv=None):
     push()
     n_jobs = server.pending
     t0 = time.perf_counter()
-    cold = server.drain()
+    cold = raise_on_errors(server.drain())
     t_cold = time.perf_counter() - t0
     push()
     t0 = time.perf_counter()
-    warm = server.drain()
+    warm = raise_on_errors(server.drain())
     t_warm = time.perf_counter() - t0
 
     cold_comp = sum({r.batched_with[0]: r.new_compilations

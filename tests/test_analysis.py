@@ -163,7 +163,8 @@ def test_seeded_bad_blockspec_is_caught():
         o_ref[...] = x_ref[...]
 
     def bad(x):
-        # block 5 over a dim of 7: interpret mode masks this, TPU would not
+        # block 5 over a dim of 7: interpret mode takes it, Mosaic refuses
+        # a sublane block that is neither a multiple of 8 nor the whole dim
         return pl.pallas_call(
             copy_kernel,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -174,7 +175,7 @@ def test_seeded_bad_blockspec_is_caught():
 
     x = jnp.ones((7, 128), jnp.float32)
     found = pallas_check.check_traceable(bad, x, name="seeded")
-    assert "pallas/block-divisibility" in [f.rule for f in found]
+    assert "pallas/tpu-tiling" in [f.rule for f in found]
 
 
 @pytest.mark.pallas

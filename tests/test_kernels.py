@@ -37,7 +37,7 @@ def test_xtv_sweep(N, p, seed):
     rng = np.random.default_rng(seed)
     X = jnp.asarray(rng.standard_normal((N, p)), jnp.float32)
     v = jnp.asarray(rng.standard_normal(N), jnp.float32)
-    out = xtv_pallas(X, v, interpret=True, block_n=64, block_p=128)
+    out = xtv_pallas(X, v, interpret=True, block_n=128, block_p=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.xtv_ref(X, v)),
                                rtol=1e-4, atol=1e-4)
 
@@ -46,9 +46,10 @@ def test_xtv_sweep(N, p, seed):
 @pytest.mark.parametrize("G,nm", [(1, 1), (5, 17), (100, 64), (257, 130)])
 def test_screen_norms_shapes(G, nm, dt):
     rng = np.random.default_rng(G * nm)
-    c = jnp.asarray(rng.standard_normal((G, nm)) * 2, dt)
-    m = jnp.asarray(rng.random((G, nm)) > 0.25)
-    s, i = screen_norms_pallas(c, m, interpret=True)
+    c = jnp.asarray(rng.standard_normal((nm, G)) * 2, dt)
+    m = jnp.asarray(rng.random((nm, G)) > 0.25)
+    s, i = screen_norms_pallas(c[:, None, :], m, interpret=True)
+    s, i = s[0], i[0]
     sr, ir = ref.screen_norms_ref(c, m)
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr), **_tol(dt))
     np.testing.assert_allclose(np.asarray(i), np.asarray(ir), **_tol(dt))
@@ -59,8 +60,8 @@ def test_screen_norms_shapes(G, nm, dt):
     ("float", 0.0, 3.0), seed=12))
 def test_sgl_prox_sweep(G, nm, seed, t_l1):
     rng = np.random.default_rng(seed)
-    v = jnp.asarray(rng.standard_normal((G, nm)) * 3, jnp.float32)
-    m = jnp.asarray(rng.random((G, nm)) > 0.3)
+    v = jnp.asarray(rng.standard_normal((nm, G)) * 3, jnp.float32)
+    m = jnp.asarray(rng.random((nm, G)) > 0.3)
     tg = jnp.asarray(np.abs(rng.standard_normal(G)), jnp.float32)
     out = sgl_prox_pallas(v, m, t_l1, tg, interpret=True, block_g=32)
     expect = ref.sgl_prox_ref(v, m, jnp.float32(t_l1), tg)
@@ -72,14 +73,14 @@ def test_kernels_match_core_library():
     """The fused kernels implement exactly the core-library semantics used by
     tlfre_screen + sgl_prox (integration contract)."""
     from repro.core import GroupSpec, shrink, group_norms, group_max_abs, sgl_prox
-    from repro.core.groups import pad_groups
+    from repro.core.groups import pad_slots
     rng = np.random.default_rng(0)
     spec = GroupSpec.from_sizes(rng.integers(1, 9, size=40))
     p = spec.num_features
     c = jnp.asarray(rng.standard_normal(p) * 2)
-    c_pad = pad_groups(spec, c)
-    s2, cinf = screen_norms_pallas(c_pad.astype(jnp.float32),
-                                   spec.pad_mask, interpret=True)
+    c_slots = pad_slots(spec, c[None].astype(jnp.float32))   # (n_max, 1, G)
+    s2, cinf = screen_norms_pallas(c_slots, spec.pad_mask.T, interpret=True)
+    s2, cinf = s2[0], cinf[0]
     np.testing.assert_allclose(
         np.asarray(jnp.sqrt(s2)),
         np.asarray(group_norms(spec, shrink(c))).astype(np.float32), rtol=1e-5)
@@ -88,11 +89,11 @@ def test_kernels_match_core_library():
         np.asarray(group_max_abs(spec, c)).astype(np.float32), rtol=1e-6)
 
     t_l1, t_g = 0.3, jnp.asarray(0.2 * np.asarray(spec.weights))
-    out_pad = sgl_prox_pallas(pad_groups(spec, c).astype(jnp.float32),
-                              spec.pad_mask, t_l1,
-                              t_g.astype(jnp.float32), interpret=True)
+    out_slots = sgl_prox_pallas(pad_slots(spec, c.astype(jnp.float32)),
+                                spec.pad_mask.T, t_l1,
+                                t_g.astype(jnp.float32), interpret=True)
     expect = sgl_prox(spec, c, t_l1, t_g)
-    got = np.asarray(out_pad)[np.asarray(spec.pad_mask)]
+    got = np.asarray(out_slots).T[np.asarray(spec.pad_mask)]
     np.testing.assert_allclose(got, np.asarray(expect), rtol=1e-5, atol=1e-6)
 
 
@@ -117,58 +118,60 @@ RAGGED_SIZES = [
 
 
 def _ragged_layout(sizes, seed):
-    """Padded (G, n_max) layout for a ragged GroupSpec with GARBAGE in the
-    invalid slots — the kernels must mask them, not read them."""
+    """Slot-major (n_max, G) layout for a ragged GroupSpec with GARBAGE in
+    the invalid slots — the kernels must mask them, not read them.  Returns
+    (spec, slot mask (n_max, G), clean, dirty)."""
     from repro.core import GroupSpec
-    from repro.core.groups import pad_groups
+    from repro.core.groups import pad_slots
     spec = GroupSpec.from_sizes(sizes)
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal(spec.num_features) * 2, jnp.float32)
-    clean = pad_groups(spec, x).astype(jnp.float32)
+    mask = spec.pad_mask.T
+    clean = jnp.where(mask, pad_slots(spec, x), 0.0)
     garbage = jnp.asarray(
         rng.standard_normal(clean.shape) * 1e6, jnp.float32)
-    dirty = jnp.where(spec.pad_mask, clean, garbage)
-    return spec, clean, dirty
+    dirty = jnp.where(mask, clean, garbage)
+    return spec, mask, clean, dirty
 
 
 @pytest.mark.pallas
 @pytest.mark.parametrize("sizes", RAGGED_SIZES)
 def test_screen_norms_ragged_masks_padded_lanes(sizes):
-    spec, clean, dirty = _ragged_layout(sizes, seed=sum(sizes))
-    s, i = screen_norms_pallas(dirty, spec.pad_mask, interpret=True,
+    spec, mask, clean, dirty = _ragged_layout(sizes, seed=sum(sizes))
+    s, i = screen_norms_pallas(dirty[:, None, :], mask, interpret=True,
                                block_g=32)
-    sr, ir = ref.screen_norms_ref(clean, spec.pad_mask)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-5,
+    sr, ir = ref.screen_norms_ref(clean, mask)
+    np.testing.assert_allclose(np.asarray(s[0]), np.asarray(sr), rtol=1e-5,
                                atol=1e-5)
-    np.testing.assert_allclose(np.asarray(i), np.asarray(ir), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(i[0]), np.asarray(ir), rtol=1e-6)
 
 
 @pytest.mark.pallas
 @pytest.mark.parametrize("sizes", RAGGED_SIZES)
 def test_sgl_prox_ragged_masks_padded_lanes(sizes):
-    spec, clean, dirty = _ragged_layout(sizes, seed=len(sizes))
+    spec, mask, clean, dirty = _ragged_layout(sizes, seed=len(sizes))
     t_l1 = 0.4
     tg = jnp.asarray(0.3 * np.asarray(spec.weights), jnp.float32)
-    out = sgl_prox_pallas(dirty, spec.pad_mask, t_l1, tg, interpret=True,
+    out = sgl_prox_pallas(dirty, mask, t_l1, tg, interpret=True,
                           block_g=32)
-    expect = ref.sgl_prox_ref(clean, spec.pad_mask, jnp.float32(t_l1), tg)
+    expect = ref.sgl_prox_ref(clean, mask, jnp.float32(t_l1), tg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
-    # padded lanes must come out exactly zero (the engine scatters them back)
-    assert float(jnp.max(jnp.abs(jnp.where(spec.pad_mask, 0.0, out)))) == 0.0
+    # padded slots must come out exactly zero (the engine scatters them back)
+    assert float(jnp.max(jnp.abs(jnp.where(mask, 0.0, out)))) == 0.0
 
 
 @pytest.mark.pallas
 def test_screen_norms_batched_grid_layout():
-    """The (L, G, n_max) grid fold used by the batched path engine."""
-    spec, clean, dirty = _ragged_layout(RAGGED_SIZES[0], seed=0)
+    """The (n_max, L, G) grid layout used by the batched path engine."""
+    spec, mask, clean, dirty = _ragged_layout(RAGGED_SIZES[0], seed=0)
     rng = np.random.default_rng(1)
     L = 5
     scales = jnp.asarray(rng.uniform(0.2, 3.0, L), jnp.float32)
-    grid_dirty = scales[:, None, None] * dirty[None]
-    s, i = ops.screen_norms_batched(grid_dirty, spec.pad_mask)
+    grid_dirty = scales[None, :, None] * dirty[:, None, :]
+    s, i = ops.screen_norms_batched(grid_dirty, mask)
     for r in range(L):
-        sr, ir = ref.screen_norms_ref(scales[r] * clean, spec.pad_mask)
+        sr, ir = ref.screen_norms_ref(scales[r] * clean, mask)
         np.testing.assert_allclose(np.asarray(s[r]), np.asarray(sr),
                                    rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(np.asarray(i[r]), np.asarray(ir),
@@ -178,20 +181,19 @@ def test_screen_norms_batched_grid_layout():
 @pytest.mark.pallas
 @pytest.mark.parametrize("sizes", RAGGED_SIZES)
 def test_screen_norms_folds_matches_per_row_kernel(sizes):
-    """The (K, L, G, n_max) fold-stack layout of the CV engine: every
-    (fold, lambda) slice must match the single-row kernel, garbage in the
-    padded lanes masked."""
-    spec, clean, dirty = _ragged_layout(sizes, seed=sum(sizes) + 1)
+    """The (n_max, K, L, G) fold-stack layout of the CV engine: every
+    (fold, lambda) slice must match the single-row oracle, garbage in the
+    padded slots masked."""
+    spec, mask, clean, dirty = _ragged_layout(sizes, seed=sum(sizes) + 1)
     rng = np.random.default_rng(2)
     K, L = 3, 4
     scales = jnp.asarray(rng.uniform(0.2, 3.0, (K, L)), jnp.float32)
-    stack_dirty = scales[:, :, None, None] * dirty[None, None]
-    s, i = ops.screen_norms_folds(stack_dirty, spec.pad_mask)
+    stack_dirty = scales[None, :, :, None] * dirty[:, None, None, :]
+    s, i = ops.screen_norms_folds(stack_dirty, mask)
     assert s.shape == (K, L, spec.num_groups)
     for k in range(K):
         for r in range(L):
-            sr, ir = ref.screen_norms_ref(scales[k, r] * clean,
-                                          spec.pad_mask)
+            sr, ir = ref.screen_norms_ref(scales[k, r] * clean, mask)
             np.testing.assert_allclose(np.asarray(s[k, r]), np.asarray(sr),
                                        rtol=1e-4, atol=1e-4)
             np.testing.assert_allclose(np.asarray(i[k, r]), np.asarray(ir),

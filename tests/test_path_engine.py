@@ -15,7 +15,8 @@ import pytest
 
 from repro.core import (GroupSpec, column_norms, group_spectral_norms,
                         lambda_max_sgl, lambda_max_nn, nn_lasso_path,
-                        normal_vector_sgl, normal_vector_nn, sgl_path,
+                        normal_vector_sgl, normal_vector_nn, Plan, Problem,
+                        SGLSession, sgl_path,
                         solve_sgl, solve_nn_lasso, spectral_norm,
                         default_lambda_grid)
 from repro.core.dpc import dpc_screen_grid
@@ -83,6 +84,30 @@ def test_nn_engine_parity(screen):
     assert stats.n_segments < J
     assert stats.n_compilations <= (
         math.ceil(math.log2(p)) + math.ceil(math.log2(J)) + 4)
+
+
+@pytest.mark.parametrize("kind", ["sgl", "nn", "sgl-cv"])
+def test_capped_rows_are_counted_uncertified(kind):
+    """A row whose solve stops at ``max_iter`` above ``tol`` is kept, so
+    the path goes on, and counted in ``n_uncertified``; a path whose rows
+    all converge counts none."""
+    if kind == "sgl-cv":
+        X, y, spec = _sgl_problem()
+        sess = SGLSession(Problem.sgl(X, y, spec))
+        run = lambda **kw: sess.cv(Plan(n_lambdas=8, n_folds=3, **kw))
+    elif kind == "sgl":
+        X, y, spec = _sgl_problem()
+        run = lambda **kw: sgl_path(X, y, spec, 1.0, n_lambdas=8,
+                                    engine="batched", min_bucket=32, **kw)
+    else:
+        X, y = _nn_problem()
+        run = lambda **kw: nn_lasso_path(X, y, n_lambdas=8, engine="batched",
+                                         min_bucket=32, **kw)
+    capped = run(tol=1e-13, max_iter=20)
+    assert capped.stats.n_uncertified > 0
+    betas = capped.fold_betas if kind == "sgl-cv" else capped.betas
+    assert np.all(np.isfinite(betas))
+    assert run(tol=1e-8, max_iter=200_000).stats.n_uncertified == 0
 
 
 def test_engine_accepts_custom_lambda_grid():
