@@ -1,0 +1,10 @@
+"""Seconds per path in ``setup.col_norms``: the column norms of X, synced."""
+from bench.program_spans import seconds
+
+LAYER = "path engine setup (core/path_engine.py)"
+UNIT, BETTER, SOURCE = "s", "lower", "program_span"
+MOVES, TASK = "path_s", "path"
+
+
+def read(run):
+    return seconds(run, "setup.col_norms")

@@ -150,8 +150,8 @@ def _is_sync_call(node: ast.Call) -> bool:
         return True
     if name in _SYNC_NP and _call_root(node) in ("np", "numpy"):
         return True
-    if name == "device_get":
-        return True
+    if name in ("device_get", "_pull"):     # _pull: the engine's counted
+        return True                         # np.asarray (core/path_engine)
     return False
 
 

@@ -60,12 +60,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import spans
 from .dpc import dpc_screen_grid_folds, gap_safe_screen_grid_nn, lambda_max_nn
 from .fenchel import shrink, weighted_l1
 from .groups import GroupSpec, group_norms
@@ -526,14 +526,14 @@ class _FoldEngine:
         rem = _build_rem(self.lambdas, self.j_pos, act)
         if self.screen_mode == "none":
             return np.ones((len(act), rem.shape[1], self.p), dtype=bool)
-        ts = time.perf_counter()
-        fk_np = np.asarray(self._screen_call(act, rem))  # one host sync
-        self.stats.n_screens += 1                        # ONE GEMM issued
-        # the sharded screen route is jnp-only — the fused fold-stack
-        # kernels only ever run on the unsharded path
-        self.stats.n_pallas_screens += int(self.pallas
-                                           and self.fshard is None)
-        self.screen_time += time.perf_counter() - ts
+        with spans.span("fold.screen") as sp:
+            fk_np = np.asarray(self._screen_call(act, rem))  # one host sync
+            self.stats.n_screens += 1                        # ONE GEMM issued
+            # the sharded screen route is jnp-only — the fused fold-stack
+            # kernels only ever run on the unsharded path
+            self.stats.n_pallas_screens += int(self.pallas
+                                               and self.fshard is None)
+        self.screen_time += sp.seconds
         return fk_np
 
     def harvest(self, launch: _Launch):
@@ -544,31 +544,31 @@ class _FoldEngine:
         transferred.  Row 0 of every fold is solved on a provably safe
         superset, so kk >= 1 guarantees progress; a row 0 that stopped at
         ``max_iter`` uncertified is kept and counted in ``n_uncertified``."""
-        ts = time.perf_counter()
-        betas_b, thetas_b, cthetas_b, good_b, iters_b = launch.outputs
-        good_np = np.asarray(good_b)                 # one host sync
-        accepted = []
-        for t, (k, _, mk, limited) in enumerate(launch.sweep):
-            good = good_np[t][:mk]
-            kk = int(np.argmin(good)) if not good.all() else mk
-            if kk == 0:
-                kk = 1
-                self.stats.n_uncertified += 1
-            self.stats.n_rejected += int(mk - kk)
-            col_idx = launch.col_idxs[t]
-            rows = np.zeros((kk, self.p))
-            rows[:, col_idx] = np.asarray(betas_b[t, :kk, :len(col_idx)])
-            j0 = self.j_pos[k]
-            self.betas_out[k, j0:j0 + kk] = rows
-            self.iters_out[k, j0:j0 + kk] = np.asarray(iters_b[t, :kk])
-            self.kept_out[k, j0:j0 + kk] = len(col_idx)
-            self.Beta[k] = rows[-1]
-            self.Theta[k] = np.asarray(thetas_b[t, kk - 1])
-            self.Cprev[k] = np.asarray(cthetas_b[t, kk - 1])
-            self.lam_bar[k] = float(launch.lam_pads[t, kk - 1])
-            self.j_pos[k] += kk
-            accepted.append((k, kk, mk, limited))
-        self.solve_time += time.perf_counter() - ts
+        with spans.span("fold.solve") as sp:
+            betas_b, thetas_b, cthetas_b, good_b, iters_b = launch.outputs
+            good_np = np.asarray(good_b)                 # one host sync
+            accepted = []
+            for t, (k, _, mk, limited) in enumerate(launch.sweep):
+                good = good_np[t][:mk]
+                kk = int(np.argmin(good)) if not good.all() else mk
+                if kk == 0:
+                    kk = 1
+                    self.stats.n_uncertified += 1
+                self.stats.n_rejected += int(mk - kk)
+                col_idx = launch.col_idxs[t]
+                rows = np.zeros((kk, self.p))
+                rows[:, col_idx] = np.asarray(betas_b[t, :kk, :len(col_idx)])
+                j0 = self.j_pos[k]
+                self.betas_out[k, j0:j0 + kk] = rows
+                self.iters_out[k, j0:j0 + kk] = np.asarray(iters_b[t, :kk])
+                self.kept_out[k, j0:j0 + kk] = len(col_idx)
+                self.Beta[k] = rows[-1]
+                self.Theta[k] = np.asarray(thetas_b[t, kk - 1])
+                self.Cprev[k] = np.asarray(cthetas_b[t, kk - 1])
+                self.lam_bar[k] = float(launch.lam_pads[t, kk - 1])
+                self.j_pos[k] += kk
+                accepted.append((k, kk, mk, limited))
+        self.solve_time += sp.seconds
         self.stats.buckets.append(
             (launch.p_b, launch.g_b, max(mk for _, _, mk, _ in launch.sweep),
              min(kk for _, kk, _, _ in accepted)))
@@ -748,66 +748,68 @@ class _SGLFoldEngine(_FoldEngine):
             screen=self.screen_mode, use_pallas=self.pallas)
 
     def make_launch(self, cohort) -> _Launch:
-        ts = time.perf_counter()
-        N, p, G = self.N, self.p, self.G
-        p_b = max(_feature_bucket(int(fkk[0].sum()), p, self.min_bucket,
-                                  self.margin)
-                  for _, fkk, _, _ in cohort)
-        S_list = [_expand_set(fkk[0], fkk, p_b) for _, fkk, _, _ in cohort]
-        g_b = min(max(_bucket(len(np.unique(self.gid[S])) + 2,
-                              self.min_group_bucket) for S in S_list), G + 1)
-        for (k, _, _, _), S in zip(cohort, S_list):
-            # same margin rule as the single-fold engine, per-fold c_prev
-            margin_fill_sgl(S, self.Cprev[k], self.gid, self.sizes_np,
-                            self.weights_np, p_b, g_b, self.fw_np)
+        with spans.span("fold.solve") as sp:
+            N, p, G = self.N, self.p, self.G
+            p_b = max(_feature_bucket(int(fkk[0].sum()), p, self.min_bucket,
+                                      self.margin)
+                      for _, fkk, _, _ in cohort)
+            S_list = [_expand_set(fkk[0], fkk, p_b) for _, fkk, _, _ in cohort]
+            g_b = min(max(_bucket(len(np.unique(self.gid[S])) + 2,
+                                  self.min_group_bucket)
+                          for S in S_list), G + 1)
+            for (k, _, _, _), S in zip(cohort, S_list):
+                # same margin rule as the single-fold engine, per-fold c_prev
+                margin_fill_sgl(S, self.Cprev[k], self.gid, self.sizes_np,
+                                self.weights_np, p_b, g_b, self.fw_np)
 
-        Ka = len(cohort)
-        m_ks = [mk for _, _, mk, _ in cohort]
-        len2 = _pow2_len(max(m_ks))
-        X_subs = np.zeros((Ka, N, p_b), dtype=self.X_np.dtype)
-        beta0s = np.zeros((Ka, p_b), dtype=self.X_np.dtype)
-        lam_pads = np.zeros((Ka, len2))
-        valids = np.zeros((Ka, len2), dtype=bool)
-        sub_specs = []
-        col_idxs = []
-        for t, ((k, _, mk, _), S) in enumerate(zip(cohort, S_list)):
-            sub_spec, col_idx = self.spec.bucketed_subset(S, p_b, g_b)
-            cols = self.X_np[:, col_idx]
+            Ka = len(cohort)
+            m_ks = [mk for _, _, mk, _ in cohort]
+            len2 = _pow2_len(max(m_ks))
+            X_subs = np.zeros((Ka, N, p_b), dtype=self.X_np.dtype)
+            beta0s = np.zeros((Ka, p_b), dtype=self.X_np.dtype)
+            lam_pads = np.zeros((Ka, len2))
+            valids = np.zeros((Ka, len2), dtype=bool)
+            sub_specs = []
+            col_idxs = []
+            for t, ((k, _, mk, _), S) in enumerate(zip(cohort, S_list)):
+                sub_spec, col_idx = self.spec.bucketed_subset(S, p_b, g_b)
+                cols = self.X_np[:, col_idx]
+                if self.centered:
+                    cols = cols - self.mus_np[k][col_idx][None, :]
+                X_subs[t, :, :len(col_idx)] = cols * self.masks_np[k][:, None]
+                beta0s[t, :len(col_idx)] = self.Beta[k][col_idx]
+                chunk = self.lambdas[self.j_pos[k]:self.j_pos[k] + mk]
+                lam_pads[t, :mk] = chunk
+                lam_pads[t, mk:] = chunk[-1]
+                valids[t, :mk] = True
+                sub_specs.append(sub_spec)
+                col_idxs.append(col_idx)
+            X = self.X
+            X_subs_d = jnp.asarray(X_subs)
+            L_subs = _spectral_norms_f(X_subs_d)
+            # cover every jit-cache-discriminating dim: persistent compile_keys
+            # sets span calls (and, in serving, problems of different N/dtype)
+            key = ("sgl-folds", Ka, N, p, G, str(X.dtype), self.max_iter,
+                   self.check_every, self.mesh, p_b, g_b, self.spec.max_size,
+                   len2, self.centered, self.pallas, self.loss.name)
+            if key not in self.seen_keys:
+                self.seen_keys.add(key)
+                self.stats.n_compilations += 1
+            k_rows = jnp.asarray(np.asarray([k for k, _, _, _ in cohort]))
+            runner = _fold_sweep("sgl", self.mesh, Ka, self.max_iter,
+                                 self.check_every, self.centered, self.pallas,
+                                 loss=self.loss)
+            sweep_args = [
+                X, X_subs_d, self.Y[k_rows], self.spec,
+                _stack_specs(sub_specs),
+                self.alpha, L_subs, jnp.asarray(lam_pads, X.dtype),
+                jnp.asarray(valids), jnp.asarray(beta0s), self.tol,
+                jnp.asarray(self.gap_scales[[k for k, _, _, _ in cohort]],
+                            X.dtype)]
             if self.centered:
-                cols = cols - self.mus_np[k][col_idx][None, :]
-            X_subs[t, :, :len(col_idx)] = cols * self.masks_np[k][:, None]
-            beta0s[t, :len(col_idx)] = self.Beta[k][col_idx]
-            chunk = self.lambdas[self.j_pos[k]:self.j_pos[k] + mk]
-            lam_pads[t, :mk] = chunk
-            lam_pads[t, mk:] = chunk[-1]
-            valids[t, :mk] = True
-            sub_specs.append(sub_spec)
-            col_idxs.append(col_idx)
-        X = self.X
-        X_subs_d = jnp.asarray(X_subs)
-        L_subs = _spectral_norms_f(X_subs_d)
-        # cover every jit-cache-discriminating dim: persistent compile_keys
-        # sets span calls (and, in serving, problems of different N/dtype)
-        key = ("sgl-folds", Ka, N, p, G, str(X.dtype), self.max_iter,
-               self.check_every, self.mesh, p_b, g_b, self.spec.max_size,
-               len2, self.centered, self.pallas, self.loss.name)
-        if key not in self.seen_keys:
-            self.seen_keys.add(key)
-            self.stats.n_compilations += 1
-        k_rows = jnp.asarray(np.asarray([k for k, _, _, _ in cohort]))
-        runner = _fold_sweep("sgl", self.mesh, Ka, self.max_iter,
-                             self.check_every, self.centered, self.pallas,
-                             loss=self.loss)
-        sweep_args = [
-            X, X_subs_d, self.Y[k_rows], self.spec, _stack_specs(sub_specs),
-            self.alpha, L_subs, jnp.asarray(lam_pads, X.dtype),
-            jnp.asarray(valids), jnp.asarray(beta0s), self.tol,
-            jnp.asarray(self.gap_scales[[k for k, _, _, _ in cohort]],
-                        X.dtype)]
-        if self.centered:
-            sweep_args.append(self.mus_d[k_rows])
-        outputs = runner(*sweep_args)                # asynchronous dispatch
-        self.solve_time += time.perf_counter() - ts
+                sweep_args.append(self.mus_d[k_rows])
+            outputs = runner(*sweep_args)            # asynchronous dispatch
+        self.solve_time += sp.seconds
         return _Launch(sweep=cohort, col_idxs=col_idxs, lam_pads=lam_pads,
                        outputs=outputs, p_b=p_b, g_b=g_b)
 
@@ -860,52 +862,52 @@ class _NNFoldEngine(_FoldEngine):
             use_pallas=self.pallas)
 
     def make_launch(self, cohort) -> _Launch:
-        ts = time.perf_counter()
-        N, p = self.N, self.p
-        p_b = max(_feature_bucket(int(fkk[0].sum()), p, self.min_bucket,
-                                  self.margin)
-                  for _, fkk, _, _ in cohort)
-        S_list = [_expand_set(fkk[0], fkk, p_b) for _, fkk, _, _ in cohort]
-        for (k, _, _, _), S in zip(cohort, S_list):
-            margin_fill_nn(S, self.Cprev[k], p_b)
+        with spans.span("fold.solve") as sp:
+            N, p = self.N, self.p
+            p_b = max(_feature_bucket(int(fkk[0].sum()), p, self.min_bucket,
+                                      self.margin)
+                      for _, fkk, _, _ in cohort)
+            S_list = [_expand_set(fkk[0], fkk, p_b) for _, fkk, _, _ in cohort]
+            for (k, _, _, _), S in zip(cohort, S_list):
+                margin_fill_nn(S, self.Cprev[k], p_b)
 
-        Ka = len(cohort)
-        m_ks = [mk for _, _, mk, _ in cohort]
-        len2 = _pow2_len(max(m_ks))
-        X_subs = np.zeros((Ka, N, p_b), dtype=self.X_np.dtype)
-        beta0s = np.zeros((Ka, p_b), dtype=self.X_np.dtype)
-        lam_pads = np.zeros((Ka, len2))
-        valids = np.zeros((Ka, len2), dtype=bool)
-        col_idxs = []
-        for t, ((k, _, mk, _), S) in enumerate(zip(cohort, S_list)):
-            col_idx = np.nonzero(S)[0]
-            X_subs[t, :, :len(col_idx)] = (self.X_np[:, col_idx]
-                                           * self.masks_np[k][:, None])
-            beta0s[t, :len(col_idx)] = self.Beta[k][col_idx]
-            chunk = self.lambdas[self.j_pos[k]:self.j_pos[k] + mk]
-            lam_pads[t, :mk] = chunk
-            lam_pads[t, mk:] = chunk[-1]
-            valids[t, :mk] = True
-            col_idxs.append(col_idx)
-        X = self.X
-        X_subs_d = jnp.asarray(X_subs)
-        L_subs = _spectral_norms_f(X_subs_d)
-        key = ("nn-folds", Ka, N, p, str(X.dtype), self.max_iter,
-               self.check_every, self.mesh, p_b, len2, self.pallas,
-               "squared")
-        if key not in self.seen_keys:
-            self.seen_keys.add(key)
-            self.stats.n_compilations += 1
-        k_rows = jnp.asarray(np.asarray([k for k, _, _, _ in cohort]))
-        runner = _fold_sweep("nn", self.mesh, Ka, self.max_iter,
-                             self.check_every, use_pallas=self.pallas)
-        outputs = runner(
-            X, X_subs_d, self.Y[k_rows], L_subs,
-            jnp.asarray(lam_pads, X.dtype), jnp.asarray(valids),
-            jnp.asarray(beta0s), self.tol,
-            jnp.asarray(self.gap_scales[[k for k, _, _, _ in cohort]],
-                        X.dtype))
-        self.solve_time += time.perf_counter() - ts
+            Ka = len(cohort)
+            m_ks = [mk for _, _, mk, _ in cohort]
+            len2 = _pow2_len(max(m_ks))
+            X_subs = np.zeros((Ka, N, p_b), dtype=self.X_np.dtype)
+            beta0s = np.zeros((Ka, p_b), dtype=self.X_np.dtype)
+            lam_pads = np.zeros((Ka, len2))
+            valids = np.zeros((Ka, len2), dtype=bool)
+            col_idxs = []
+            for t, ((k, _, mk, _), S) in enumerate(zip(cohort, S_list)):
+                col_idx = np.nonzero(S)[0]
+                X_subs[t, :, :len(col_idx)] = (self.X_np[:, col_idx]
+                                               * self.masks_np[k][:, None])
+                beta0s[t, :len(col_idx)] = self.Beta[k][col_idx]
+                chunk = self.lambdas[self.j_pos[k]:self.j_pos[k] + mk]
+                lam_pads[t, :mk] = chunk
+                lam_pads[t, mk:] = chunk[-1]
+                valids[t, :mk] = True
+                col_idxs.append(col_idx)
+            X = self.X
+            X_subs_d = jnp.asarray(X_subs)
+            L_subs = _spectral_norms_f(X_subs_d)
+            key = ("nn-folds", Ka, N, p, str(X.dtype), self.max_iter,
+                   self.check_every, self.mesh, p_b, len2, self.pallas,
+                   "squared")
+            if key not in self.seen_keys:
+                self.seen_keys.add(key)
+                self.stats.n_compilations += 1
+            k_rows = jnp.asarray(np.asarray([k for k, _, _, _ in cohort]))
+            runner = _fold_sweep("nn", self.mesh, Ka, self.max_iter,
+                                 self.check_every, use_pallas=self.pallas)
+            outputs = runner(
+                X, X_subs_d, self.Y[k_rows], L_subs,
+                jnp.asarray(lam_pads, X.dtype), jnp.asarray(valids),
+                jnp.asarray(beta0s), self.tol,
+                jnp.asarray(self.gap_scales[[k for k, _, _, _ in cohort]],
+                            X.dtype))
+        self.solve_time += sp.seconds
         return _Launch(sweep=cohort, col_idxs=col_idxs, lam_pads=lam_pads,
                        outputs=outputs, p_b=p_b, g_b=0)
 
@@ -992,55 +994,57 @@ def sgl_fold_paths(X, y, spec: GroupSpec, alpha, masks, lambdas, *,
               and spec.feature_weights is None)
 
     # ---- per-fold geometry, batched into a handful of GEMMs ---------------
-    t0 = time.perf_counter()
-    masks_d = jnp.asarray(masks_np, X.dtype)
-    Y = masks_d * jnp.asarray(y_rows_np, X.dtype)             # (K, N)
-    col2_f = mm(masks_d, X * X)                                # (K, p)
-    if centered:
-        mus_d = jnp.asarray(mus, X.dtype)
-        # centered correlations / norms via rank-one corrections:
-        # (X - 1 mu^T)^T v = X^T v - mu (1^T v);  sum m (x-mu)^2 = col2 - n mu^2
-        xty_f = mm(Y, X) - jnp.sum(Y, axis=1)[:, None] * mus_d
-        n_train = jnp.sum(masks_d, axis=1)
-        col2_f = jnp.maximum(col2_f - n_train[:, None] * mus_d ** 2, 0.0)
-    else:
-        mus_d = None
-        xty_f = mm(Y, X)                                      # (K, p)
-    lam_max_f, g_star_f = jax.vmap(
-        lambda c: lambda_max_sgl(spec, c, alpha))(xty_f)
-    col_n_f = jnp.sqrt(col2_f)
-    if specnorm_method == "power":
-        # one fold at a time: peak memory stays (N, p), not (K, N, p) —
-        # group_spectral_norms is jitted once and reused across folds
-        gspec_f = jnp.stack([
-            group_spectral_norms(
-                masks_d[k][:, None] * (X - mus_d[k][None, :] if centered
-                                       else X), spec)
-            for k in range(K)])
-    else:
-        gspec_f = jnp.sqrt(jax.vmap(lambda c2: jax.ops.segment_sum(
-            c2, spec.group_ids, num_segments=G))(col2_f))
-    # boundary normal of Theorem 12 at each fold's own lambda_max, masked
-    lam_max_np = np.asarray(lam_max_f, dtype=float)
-    lam_max_div = jnp.asarray(np.where(lam_max_np > 0, lam_max_np, 1.0),
-                              X.dtype)
-    W = shrink(xty_f / lam_max_div[:, None])
-    w_star = jnp.where(spec.group_ids[None, :] == g_star_f[:, None], W, 0.0)
-    n_bound = mm(w_star, X.T)                                 # (K, N)
-    if centered:
-        n_bound = n_bound - jnp.sum(w_star * mus_d, axis=1)[:, None]
-    n_bound = masks_d * n_bound
-    jax.block_until_ready((col_n_f, gspec_f, n_bound))
-    # feature sharding covers the STACKED GRID SCREENS only; the per-fold
-    # stats above and the bucketed sweeps keep the full-X algebra, so the
-    # sharded fold route certifies against the identical reference numbers
-    fshard = None
-    if int(feature_shards) > 1:
-        from ..distributed.feature_shard import plan_feature_shards
-        fshard = plan_feature_shards(int(feature_shards), p, spec)
-        if fshard.n_shards <= 1:
-            fshard = None
-    setup_time = time.perf_counter() - t0
+    with spans.span("fold.setup") as sp:
+        masks_d = jnp.asarray(masks_np, X.dtype)
+        Y = masks_d * jnp.asarray(y_rows_np, X.dtype)             # (K, N)
+        col2_f = mm(masks_d, X * X)                                # (K, p)
+        if centered:
+            mus_d = jnp.asarray(mus, X.dtype)
+            # centered correlations / norms via rank-one corrections:
+            # (X - 1 mu^T)^T v = X^T v - mu (1^T v);
+            # sum m (x-mu)^2 = col2 - n mu^2
+            xty_f = mm(Y, X) - jnp.sum(Y, axis=1)[:, None] * mus_d
+            n_train = jnp.sum(masks_d, axis=1)
+            col2_f = jnp.maximum(col2_f - n_train[:, None] * mus_d ** 2, 0.0)
+        else:
+            mus_d = None
+            xty_f = mm(Y, X)                                      # (K, p)
+        lam_max_f, g_star_f = jax.vmap(
+            lambda c: lambda_max_sgl(spec, c, alpha))(xty_f)
+        col_n_f = jnp.sqrt(col2_f)
+        if specnorm_method == "power":
+            # one fold at a time: peak memory stays (N, p), not (K, N, p) —
+            # group_spectral_norms is jitted once and reused across folds
+            gspec_f = jnp.stack([
+                group_spectral_norms(
+                    masks_d[k][:, None] * (X - mus_d[k][None, :] if centered
+                                           else X), spec)
+                for k in range(K)])
+        else:
+            gspec_f = jnp.sqrt(jax.vmap(lambda c2: jax.ops.segment_sum(
+                c2, spec.group_ids, num_segments=G))(col2_f))
+        # boundary normal of Theorem 12 at each fold's own lambda_max, masked
+        lam_max_np = np.asarray(lam_max_f, dtype=float)
+        lam_max_div = jnp.asarray(np.where(lam_max_np > 0, lam_max_np, 1.0),
+                                  X.dtype)
+        W = shrink(xty_f / lam_max_div[:, None])
+        w_star = jnp.where(spec.group_ids[None, :] == g_star_f[:, None], W,
+                           0.0)
+        n_bound = mm(w_star, X.T)                                 # (K, N)
+        if centered:
+            n_bound = n_bound - jnp.sum(w_star * mus_d, axis=1)[:, None]
+        n_bound = masks_d * n_bound
+        jax.block_until_ready((col_n_f, gspec_f, n_bound))
+        # feature sharding covers the STACKED GRID SCREENS only; the per-fold
+        # stats above and the bucketed sweeps keep the full-X algebra, so the
+        # sharded fold route certifies against the identical reference numbers
+        fshard = None
+        if int(feature_shards) > 1:
+            from ..distributed.feature_shard import plan_feature_shards
+            fshard = plan_feature_shards(int(feature_shards), p, spec)
+            if fshard.n_shards <= 1:
+                fshard = None
+    setup_time = sp.seconds
 
     stats = EngineStats()
     seen_keys = compile_keys if compile_keys is not None else set()
@@ -1099,22 +1103,22 @@ def nn_fold_paths(X, y, masks, lambdas, *, screen: str = "dpc", tol=1e-9,
     J = len(lambdas)
     pallas = _pallas_active(use_pallas, X.dtype)
 
-    t0 = time.perf_counter()
-    masks_d = jnp.asarray(masks_np, X.dtype)
-    Y = masks_d * jnp.asarray(y_rows_np, X.dtype)
-    xty_f = mm(Y, X)
-    lam_max_f, i_star_f = jax.vmap(lambda_max_nn)(xty_f)
-    col_n_f = jnp.sqrt(mm(masks_d, X * X))
-    lam_max_np = np.asarray(lam_max_f, dtype=float)
-    n_bound = masks_d * X[:, np.asarray(i_star_f)].T          # (K, N)
-    jax.block_until_ready((col_n_f, n_bound))
-    fshard = None
-    if int(feature_shards) > 1:
-        from ..distributed.feature_shard import plan_feature_shards
-        fshard = plan_feature_shards(int(feature_shards), p, None)
-        if fshard.n_shards <= 1:
-            fshard = None
-    setup_time = time.perf_counter() - t0
+    with spans.span("fold.setup") as sp:
+        masks_d = jnp.asarray(masks_np, X.dtype)
+        Y = masks_d * jnp.asarray(y_rows_np, X.dtype)
+        xty_f = mm(Y, X)
+        lam_max_f, i_star_f = jax.vmap(lambda_max_nn)(xty_f)
+        col_n_f = jnp.sqrt(mm(masks_d, X * X))
+        lam_max_np = np.asarray(lam_max_f, dtype=float)
+        n_bound = masks_d * X[:, np.asarray(i_star_f)].T          # (K, N)
+        jax.block_until_ready((col_n_f, n_bound))
+        fshard = None
+        if int(feature_shards) > 1:
+            from ..distributed.feature_shard import plan_feature_shards
+            fshard = plan_feature_shards(int(feature_shards), p, None)
+            if fshard.n_shards <= 1:
+                fshard = None
+    setup_time = sp.seconds
 
     stats = EngineStats()
     seen_keys = compile_keys if compile_keys is not None else set()

@@ -45,6 +45,7 @@ class PathResult:
     kept_features: np.ndarray           # (J,) columns entering the solver
     kept_groups: Optional[np.ndarray] = None
     stats: Optional[object] = None      # EngineStats when engine="batched"
+    spans: list = dataclasses.field(default_factory=list)  # batched engine
 
     @property
     def total_time(self):

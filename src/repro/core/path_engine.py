@@ -58,13 +58,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import spans
 from .dpc import (dpc_screen_grid, dpc_screen_grid_feat, dual_scaling_nn,
                   gap_safe_screen_grid_nn, gap_safe_screen_grid_nn_feat,
                   lambda_max_nn, normal_vector_nn)
@@ -136,6 +136,23 @@ def _pallas_active(use_pallas: Optional[bool], dtype) -> bool:
     return bool(use_pallas)
 
 
+def _pull(a) -> np.ndarray:
+    """``np.asarray(a)``, adding to the open span's ``d2h_bytes`` the bytes
+    that cross to the host: none for a host array, or for a device array
+    that already holds its host copy (jax keeps it after the first pull)."""
+    if isinstance(a, jax.Array) and getattr(a, "_npy_value", None) is None:
+        spans.add("d2h_bytes", a.nbytes)
+    return np.asarray(a)
+
+
+def _put(a, dtype=None):
+    """``jnp.asarray(a, dtype)`` of a host array, adding the bytes uploaded
+    to the open span's ``h2d_bytes``."""
+    out = jnp.asarray(a, dtype)
+    spans.add("h2d_bytes", out.nbytes)
+    return out
+
+
 def _xtv(X, v, use_pallas: bool):
     if use_pallas:
         from ..kernels import ops as _kops
@@ -202,7 +219,7 @@ def _pad_grid(lambdas_rem: np.ndarray, dtype):
     L = len(lambdas_rem)
     Lp = _pow2_len(L)
     pad = np.concatenate([lambdas_rem, np.full(Lp - L, lambdas_rem[-1])])
-    return jnp.asarray(pad, dtype), L
+    return _put(pad, dtype), L
 
 
 def _feature_bucket(n_base: int, p: int, min_bucket: int,
@@ -493,10 +510,31 @@ def _feat_sweep(kind: str, ops, max_iter: int, check_every: int):
     return fn
 
 
+
+
+def _path_verb(engine):
+    """Run ``engine`` under a root ``path`` span and return its result with
+    the spans (``PathResult.spans``) and the timers taken from them:
+    ``setup_time`` is the ``setup`` span, ``screen_time`` the sum of the
+    ``segment.screen`` spans, ``solve_time`` that of ``segment.gather`` and
+    ``segment.sweep``."""
+    @functools.wraps(engine)
+    def verb(*args, **kwargs):
+        with spans.span("path") as root:
+            res = engine(*args, **kwargs)
+        rec = spans.record(root)
+        return dataclasses.replace(
+            res, spans=rec, setup_time=spans.total(rec, "setup"),
+            screen_time=spans.total(rec, "segment.screen"),
+            solve_time=spans.total(rec, "segment.gather", "segment.sweep"))
+    return verb
+
+
 # ---------------------------------------------------------------------------
 # SGL
 # ---------------------------------------------------------------------------
 
+@_path_verb
 def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
                      n_lambdas: int = 100, min_ratio: float = 0.01,
                      screen: str = "tlfre", tol=1e-9, max_iter: int = 20000,
@@ -536,6 +574,14 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     data-fit term.  Non-squared losses screen with Gap-Safe balls only
     (TLFre's Theorem-12 ball is squared-loss algebra) and run the pure-jnp
     route (no Pallas kernels, no feature shards).
+
+    The call records its spans (``core.spans``) in ``PathResult.spans``:
+    ``path`` > ``setup`` (> ``setup.xty``, ``setup.col_norms``,
+    ``setup.group_norms``, ``setup.spectral_norm``), ``host_copy``, and one
+    ``segment`` per loop pass (> ``segment.screen``, ``segment.expand``,
+    ``segment.gather``, ``segment.sweep``, ``segment.assemble``), with the
+    array bytes each moves between host and device (``h2d_bytes``,
+    ``d2h_bytes``) and the sweep's ``rows_solved`` and ``rows_accepted``.
     """
     if screen not in ("tlfre", "gapsafe", "none"):
         raise ValueError(f"unknown screen mode {screen!r}")
@@ -564,47 +610,57 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     pallas = (_pallas_active(use_pallas, X.dtype) and fshard is None
               and squared and spec.feature_weights is None)
 
-    t0 = time.perf_counter()
-    if fshard is not None:
-        fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
-        fops = _fs.feature_ops(fshard.n_shards, fmesh)
-        Xs = fops.place(fshard.stack_columns(np.asarray(X)))
-        specs_s = fshard.specs_stacked
-        xty_s = _fs.sharded_xtv(fops, Xs, y)
-        xty_np = fshard.unshard_features(np.asarray(xty_s))
-        xty = jnp.asarray(xty_np)
-        lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
-        lam_max = float(lam_max)
-        col_n_s = _fs.sharded_column_norms(fops, Xs)
-        if specnorm_method == "power":
-            gspec_s = _fs.sharded_group_spectral_norms(fops, Xs, specs_s)
+    with spans.span("setup"):
+        if fshard is not None:
+            fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
+            fops = _fs.feature_ops(fshard.n_shards, fmesh)
+            Xs = fops.place(fshard.stack_columns(np.asarray(X)))
+            specs_s = fshard.specs_stacked
+            with spans.span("setup.xty"):
+                xty_s = _fs.sharded_xtv(fops, Xs, y)
+                xty_np = fshard.unshard_features(np.asarray(xty_s))
+                xty = jnp.asarray(xty_np)
+                lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
+                lam_max = float(lam_max)
+            with spans.span("setup.col_norms"):
+                col_n_s = jax.block_until_ready(
+                    _fs.sharded_column_norms(fops, Xs))
+            with spans.span("setup.group_norms"):
+                if specnorm_method == "power":
+                    gspec_s = _fs.sharded_group_spectral_norms(fops, Xs,
+                                                               specs_s)
+                else:
+                    gspec_s = _fs.sharded_group_frobenius_norms(fops, Xs,
+                                                                specs_s)
+                jax.block_until_ready(gspec_s)
+            # Theorem-15 boundary normal X w*, feature-parallel: w* is
+            # supported on the argmax group only, so X w* is a partial-GEMV
+            # psum
+            w_s = shrink(_fs.sharded_xtv(fops, Xs, y / lam_max))
+            gid_stack = jnp.asarray(fshard.shard_features(
+                np.asarray(spec.group_ids) + 1) - 1)            # pads -> -1
+            n_boundary = jax.block_until_ready(_fs.sharded_fit(
+                fops, Xs, jnp.where(gid_stack == g_star, w_s, 0.0)))
+            L_full = None          # only the full-bucket fallback needs it
+            r0 = y                 # sharded route is squared-loss only
         else:
-            gspec_s = _fs.sharded_group_frobenius_norms(fops, Xs, specs_s)
-        # Theorem-15 boundary normal X w*, feature-parallel: w* is supported
-        # on the argmax group only, so X w* is a partial-GEMV psum
-        w_s = shrink(_fs.sharded_xtv(fops, Xs, y / lam_max))
-        gid_stack = jnp.asarray(fshard.shard_features(
-            np.asarray(spec.group_ids) + 1) - 1)            # pads -> -1
-        n_boundary = _fs.sharded_fit(
-            fops, Xs, jnp.where(gid_stack == g_star, w_s, 0.0))
-        L_full = None          # only the full-bucket fallback needs it
-        r0 = y                 # sharded route is squared-loss only
-        jax.block_until_ready((col_n_s, gspec_s, n_boundary))
-    else:
-        # -grad of the loss at beta = 0; y itself for squared loss, so the
-        # squared setup GEMV X.T @ y is unchanged
-        r0 = loss.residual_at_zero(y)
-        xty = mm(X.T, r0)
-        lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
-        lam_max = float(lam_max)
-        col_n = column_norms(X)
-        if specnorm_method == "power":
-            gspec = group_spectral_norms(X, spec)
-        else:
-            gspec = group_frobenius_norms(X, spec)
-        L_full = spectral_norm(X) ** 2
-        jax.block_until_ready((col_n, gspec, L_full))
-    setup_time = time.perf_counter() - t0
+            with spans.span("setup.xty"):
+                # -grad of the loss at beta = 0; y itself for squared loss,
+                # so the squared setup GEMV X.T @ y is unchanged
+                r0 = loss.residual_at_zero(y)
+                xty = mm(X.T, r0)
+                lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
+                lam_max = float(lam_max)
+            with spans.span("setup.col_norms"):
+                col_n = jax.block_until_ready(column_norms(X))
+            with spans.span("setup.group_norms"):
+                if specnorm_method == "power":
+                    gspec = group_spectral_norms(X, spec)
+                else:
+                    gspec = group_frobenius_norms(X, spec)
+                jax.block_until_ready(gspec)
+            with spans.span("setup.spectral_norm"):
+                L_full = jax.block_until_ready(spectral_norm(X) ** 2)
 
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max, n_lambdas, min_ratio)
@@ -616,15 +672,14 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     kept_feat = np.zeros(J, dtype=np.int64)
     kept_grp = np.zeros(J, dtype=np.int64)
     stats = EngineStats()
-    screen_time = 0.0
-    solve_time = 0.0
-    X_np = np.asarray(X)
-    gid = np.asarray(spec.group_ids)
-    sizes_np = np.asarray(spec.sizes)
-    weights_np = np.asarray(spec.weights)
-    fw_np = (None if spec.feature_weights is None
-             else np.asarray(spec.feature_weights))
-    gap_scale = loss.gap_scale_host(y)
+    with spans.span("host_copy"):
+        X_np = _pull(X)
+        gid = _pull(spec.group_ids)
+        sizes_np = _pull(spec.sizes)
+        weights_np = _pull(spec.weights)
+        fw_np = (None if spec.feature_weights is None
+                 else _pull(spec.feature_weights))
+        gap_scale = loss.gap_scale_host(y)
 
     theta_bar = r0 / lam_max            # exact dual at lam_max (Thm 8)
     if fshard is not None:
@@ -643,197 +698,214 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
         j += 1                          # beta* = 0 at/above lam_max
 
     while j < J:
-        rem, L_rem = _pad_grid(lambdas[j:], X.dtype)
-        # ---- screen the whole remaining grid in one shot ----------------
-        ts = time.perf_counter()
-        if screen == "none":
-            fk_np = np.ones((J - j, p), dtype=bool)
-        elif fshard is not None:
-            # host-side Theorem-15 branch (lam_bar/lam_max are host floats):
-            # the boundary normal was precomputed sharded in setup
-            at_max = lam_bar >= lam_max * (1.0 - 1e-12)
-            n_vec = n_boundary if at_max else (y / lam_bar - theta_bar)
-            _, fk_s, _ = _tlfre_feat_jit(
-                fops, Xs, specs_s, y, alpha, rem, theta_bar, n_vec,
-                col_n_s, gspec_s, safety=safety)
-            if screen == "gapsafe":
-                beta_s = jnp.asarray(fshard.shard_features(
-                    beta_full.astype(X_np.dtype)))
-                resid = y - _fs.sharded_fit(fops, Xs, beta_s)
-                pen = (alpha * jnp.sum(spec.weights *
-                                       group_norms(spec, beta_dev))
-                       + jnp.sum(jnp.abs(beta_dev)))
-                radii = _gap_safe_radii_jit(y, rem, theta_bar, resid,
-                                            pen) * (1.0 + safety)
-                _, fk_dyn_s = _gap_safe_feat_jit(fops, specs_s, alpha,
-                                                 c_prev_s, radii, col_n_s,
-                                                 gspec_s)
-                fk_s = fk_s & fk_dyn_s
-            fk_np = fshard.unshard_features(
-                np.asarray(fk_s))[:L_rem]       # one host sync
-            stats.n_screens += 1
-        elif not squared:
-            # non-squared losses have no Theorem-12 ball; the Gap-Safe
-            # ball around the latest certified dual is the only safe rule
-            fit = mm(X, beta_dev)
-            resid = loss.residual(y, fit)
-            pen = (alpha * jnp.sum(spec.weights *
-                                   group_norms(spec, beta_dev))
-                   + weighted_l1(spec, beta_dev))
-            radii = _gap_safe_radii_loss_jit(
-                loss, y, rem, theta_bar, fit, resid, pen) * (1.0 + safety)
-            _, fk = _gap_safe_grid_jit(spec, alpha, c_prev, radii,
-                                       col_n, gspec, use_pallas=False)
-            fk_np = np.asarray(fk)[:L_rem]      # one host sync
-            stats.n_screens += 1
-        else:
-            n_vec = normal_vector_sgl(X, y, spec, lam_bar, lam_max,
-                                      theta_bar, g_star)
-            _, fk, _ = _tlfre_grid_jit(
-                X, y, spec, alpha, rem, lam_bar, theta_bar, n_vec,
-                col_n, gspec, safety=safety, use_pallas=pallas)
-            if screen == "gapsafe":
-                # both balls certify the dual optimum, so their
-                # intersection screens strictly harder than either alone
-                resid = y - mm(X, beta_dev)
-                pen = (alpha * jnp.sum(spec.weights *
-                                       group_norms(spec, beta_dev))
-                       + weighted_l1(spec, beta_dev))
-                radii = _gap_safe_radii_jit(y, rem, theta_bar, resid,
-                                            pen) * (1.0 + safety)
-                _, fk_dyn = _gap_safe_grid_jit(spec, alpha, c_prev, radii,
+        with spans.span("segment"):
+            # ---- screen the whole remaining grid in one shot ------------
+            rem, L_rem = _pad_grid(lambdas[j:], X.dtype)
+            with spans.span("segment.screen"):
+                if screen == "none":
+                    fk_np = np.ones((J - j, p), dtype=bool)
+                elif fshard is not None:
+                    # host-side Theorem-15 branch (lam_bar/lam_max are host
+                    # floats): the boundary normal was precomputed sharded
+                    # in setup
+                    at_max = lam_bar >= lam_max * (1.0 - 1e-12)
+                    n_vec = n_boundary if at_max else (y / lam_bar
+                                                       - theta_bar)
+                    _, fk_s, _ = _tlfre_feat_jit(
+                        fops, Xs, specs_s, y, alpha, rem, theta_bar, n_vec,
+                        col_n_s, gspec_s, safety=safety)
+                    if screen == "gapsafe":
+                        beta_s = _put(fshard.shard_features(
+                            beta_full.astype(X_np.dtype)))
+                        resid = y - _fs.sharded_fit(fops, Xs, beta_s)
+                        pen = (alpha * jnp.sum(spec.weights *
+                                               group_norms(spec, beta_dev))
+                               + jnp.sum(jnp.abs(beta_dev)))
+                        radii = _gap_safe_radii_jit(
+                            y, rem, theta_bar, resid, pen) * (1.0 + safety)
+                        _, fk_dyn_s = _gap_safe_feat_jit(
+                            fops, specs_s, alpha, c_prev_s, radii, col_n_s,
+                            gspec_s)
+                        fk_s = fk_s & fk_dyn_s
+                    fk_np = fshard.unshard_features(
+                        _pull(fk_s))[:L_rem]            # one host sync
+                    stats.n_screens += 1
+                elif not squared:
+                    # non-squared losses have no Theorem-12 ball; the
+                    # Gap-Safe ball around the latest certified dual is the
+                    # only safe rule
+                    fit = mm(X, beta_dev)
+                    resid = loss.residual(y, fit)
+                    pen = (alpha * jnp.sum(spec.weights *
+                                           group_norms(spec, beta_dev))
+                           + weighted_l1(spec, beta_dev))
+                    radii = _gap_safe_radii_loss_jit(
+                        loss, y, rem, theta_bar, fit, resid,
+                        pen) * (1.0 + safety)
+                    _, fk = _gap_safe_grid_jit(spec, alpha, c_prev, radii,
                                                col_n, gspec,
-                                               use_pallas=pallas)
-                fk = fk & fk_dyn
-            fk_np = np.asarray(fk)[:L_rem]      # one host sync
-            stats.n_screens += 1
-            stats.n_pallas_screens += int(pallas)
-        screen_time += time.perf_counter() - ts
+                                               use_pallas=False)
+                    fk_np = _pull(fk)[:L_rem]           # one host sync
+                    stats.n_screens += 1
+                else:
+                    n_vec = normal_vector_sgl(X, y, spec, lam_bar, lam_max,
+                                              theta_bar, g_star)
+                    _, fk, _ = _tlfre_grid_jit(
+                        X, y, spec, alpha, rem, lam_bar, theta_bar, n_vec,
+                        col_n, gspec, safety=safety, use_pallas=pallas)
+                    if screen == "gapsafe":
+                        # both balls certify the dual optimum, so their
+                        # intersection screens strictly harder than either
+                        resid = y - mm(X, beta_dev)
+                        pen = (alpha * jnp.sum(spec.weights *
+                                               group_norms(spec, beta_dev))
+                               + weighted_l1(spec, beta_dev))
+                        radii = _gap_safe_radii_jit(
+                            y, rem, theta_bar, resid, pen) * (1.0 + safety)
+                        _, fk_dyn = _gap_safe_grid_jit(
+                            spec, alpha, c_prev, radii, col_n, gspec,
+                            use_pallas=pallas)
+                        fk = fk & fk_dyn
+                    fk_np = _pull(fk)[:L_rem]           # one host sync
+                    stats.n_screens += 1
+                    stats.n_pallas_screens += int(pallas)
 
-        row_counts = fk_np.sum(axis=1)
-        if row_counts[0] == 0:
-            # fully-screened prefix: beta* = 0 and the dual optimum is y/lam
-            k = (int(np.argmax(row_counts > 0)) if row_counts.any()
-                 else len(row_counts))
-            lam_bar = float(lambdas[j + k - 1])
-            theta_bar = r0 / lam_bar
-            if fshard is not None:
-                c_prev_s = xty_s / lam_bar
-                c_prev = xty_np / lam_bar
-            else:
-                c_prev = xty / lam_bar
-            beta_dev = jnp.zeros(p, X.dtype)
-            beta_full = np.zeros(p)
+            # ---- feature set: safe base + nearby-row union + margin -----
+            with spans.span("segment.expand"):
+                row_counts = fk_np.sum(axis=1)
+                if row_counts[0] == 0:
+                    # fully-screened prefix: beta* = 0 and the dual optimum
+                    # is y/lam
+                    k = (int(np.argmax(row_counts > 0)) if row_counts.any()
+                         else len(row_counts))
+                    lam_bar = float(lambdas[j + k - 1])
+                    theta_bar = r0 / lam_bar
+                    if fshard is not None:
+                        c_prev_s = xty_s / lam_bar
+                        c_prev = xty_np / lam_bar
+                    else:
+                        c_prev = xty / lam_bar
+                    beta_dev = jnp.zeros(p, X.dtype)
+                    beta_full = np.zeros(p)
+                    j += k
+                    continue
+                base = fk_np[0]
+                n_base = int(base.sum())
+                p_b = _feature_bucket(n_base, p, min_bucket, margin)
+                S = _expand_set(base, fk_np, p_b)
+                g_S = np.unique(gid[S])
+                g_b = min(_bucket(len(g_S) + 2, min_group_bucket), G + 1)
+                margin_fill_sgl(S, _pull(c_prev), gid, sizes_np,
+                                weights_np, p_b, g_b, fw_np)
+
+            m = min(J - j, spec_m)
+
+            # ---- bucketed reduced problem + one jitted sweep ------------
+            with spans.span("segment.gather"):
+                if S.all():
+                    sub_spec, col_idx = spec, np.arange(p)
+                    if L_full is None:
+                        L_full = jax.block_until_ready(
+                            spectral_norm(X) ** 2)
+                    X_sub, L_sub = X, L_full
+                    p_b, g_b = p, G
+                else:
+                    sub_spec, col_idx = spec.bucketed_subset(S, p_b, g_b)
+                    X_s = np.zeros((N, p_b), dtype=X_np.dtype)
+                    X_s[:, :len(col_idx)] = X_np[:, col_idx]
+                    X_sub = _put(X_s)
+                    L_sub = jax.block_until_ready(
+                        spectral_norm(X_sub, iters=25) ** 2)
+
+            with spans.span("segment.sweep"):
+                beta0 = np.zeros(p_b, dtype=X_np.dtype)
+                beta0[:len(col_idx)] = beta_full[col_idx]
+                lam_chunk = lambdas[j:j + m]
+                len2 = _pow2_len(m)
+                # pad to a power of two so compile keys are reused; padded
+                # steps are masked out via lax.cond inside the sweep
+                lam_pad = np.concatenate(
+                    [lam_chunk, np.full(len2 - m, lam_chunk[-1])])
+                valid = np.arange(len2) < m
+                # the key must cover every dim jax's jit cache discriminates
+                # on — a persistent compile_keys set spans problems
+                # (serving), so shape and static args belong in it, not
+                # just the bucket dims; the loss name rides at the END so
+                # positional readers stay valid
+                if fshard is not None:
+                    key = ("sgl-feat", fshard.n_shards, N, p, G,
+                           str(X.dtype), max_iter, check_every,
+                           fmesh is not None, p_b, sub_spec.num_groups,
+                           sub_spec.max_size, len2, loss.name)
+                else:
+                    key = ("sgl", N, p, G, str(X.dtype), max_iter,
+                           check_every, pallas, p_b, sub_spec.num_groups,
+                           sub_spec.max_size, len2, loss.name)
+                if key not in seen_keys:
+                    seen_keys.add(key)
+                    stats.n_compilations += 1
+                lam_d = _put(lam_pad, X.dtype)
+                valid_d, beta0_d = _put(valid), _put(beta0)
+                if fshard is not None:
+                    betas_b, thetas_b, cthetas_b, good_b, iters_b = \
+                        _feat_sweep("sgl", fops, max_iter, check_every)(
+                            Xs, X_sub, y, specs_s, sub_spec, alpha, L_sub,
+                            lam_d, valid_d, beta0_d, tol, gap_scale)
+                else:
+                    betas_b, thetas_b, cthetas_b, good_b, iters_b = \
+                        _sweep_sgl(
+                            X, X_sub, y, spec, sub_spec, alpha, L_sub,
+                            lam_d, valid_d, beta0_d, tol, gap_scale,
+                            max_iter=max_iter, check_every=check_every,
+                            use_pallas=pallas, loss=loss)
+                good_np = _pull(good_b[:m])         # one host sync
+                k = int(np.argmin(good_np)) if not good_np.all() else m
+                if k == 0:
+                    # row 0 (solved on a provably safe set) stopped at
+                    # max_iter: keep its best iterate so the path
+                    # progresses, flagged
+                    k = 1
+                    stats.n_uncertified += 1
+                stats.n_rejected += int(m - k)
+                theta_bar = thetas_b[k - 1]
+                if fshard is not None:
+                    c_prev_s = cthetas_b[k - 1]
+                    c_prev = fshard.unshard_features(_pull(c_prev_s))
+                else:
+                    c_prev = cthetas_b[k - 1]
+                betas_np = _pull(betas_b[:k])
+                iters_np = _pull(iters_b[:k])
+                jax.block_until_ready(theta_bar)
+                spans.add("rows_solved", m)
+                spans.add("rows_accepted", k)
+
+            with spans.span("segment.assemble"):
+                chunk_rows = np.zeros((k, p))
+                chunk_rows[:, col_idx] = betas_np[:, :len(col_idx)]
+                betas[j:j + k] = chunk_rows
+                iters[j:j + k] = iters_np
+                kept_feat[j:j + k] = len(col_idx)   # columns in the solver
+                kept_grp[j:j + k] = len(np.unique(gid[S]))
+                beta_full = chunk_rows[-1]
+                beta_dev = _put(beta_full, X.dtype)
+            lam_bar = float(lam_chunk[k - 1])
+            stats.n_segments += 1
+            stats.buckets.append((p_b, g_b, m, k))
+            spec_m = min(2 * spec_m, 64) if k == m else max(2, k)
             j += k
-            continue
 
-        # ---- feature set: safe base + nearby-row union + ranked margin --
-        base = fk_np[0]
-        n_base = int(base.sum())
-        p_b = _feature_bucket(n_base, p, min_bucket, margin)
-        S = _expand_set(base, fk_np, p_b)
-        g_S = np.unique(gid[S])
-        g_b = min(_bucket(len(g_S) + 2, min_group_bucket), G + 1)
-        margin_fill_sgl(S, np.asarray(c_prev), gid, sizes_np, weights_np,
-                        p_b, g_b, fw_np)
-
-        m = min(J - j, spec_m)
-
-        # ---- bucketed reduced problem + one jitted sweep over the chunk --
-        ts = time.perf_counter()
-        if S.all():
-            sub_spec, col_idx = spec, np.arange(p)
-            if L_full is None:
-                L_full = spectral_norm(X) ** 2
-            X_sub, L_sub = X, L_full
-            p_b, g_b = p, G
-        else:
-            sub_spec, col_idx = spec.bucketed_subset(S, p_b, g_b)
-            X_s = np.zeros((N, p_b), dtype=X_np.dtype)
-            X_s[:, :len(col_idx)] = X_np[:, col_idx]
-            X_sub = jnp.asarray(X_s)
-            L_sub = spectral_norm(X_sub, iters=25) ** 2
-        beta0 = np.zeros(p_b, dtype=X_np.dtype)
-        beta0[:len(col_idx)] = beta_full[col_idx]
-
-        lam_chunk = lambdas[j:j + m]
-        len2 = _pow2_len(m)
-        # pad to a power of two so compile keys are reused; padded steps
-        # are masked out via lax.cond inside the sweep
-        lam_pad = np.concatenate(
-            [lam_chunk, np.full(len2 - m, lam_chunk[-1])])
-        valid = np.arange(len2) < m
-        # the key must cover every dim jax's jit cache discriminates on —
-        # a persistent compile_keys set spans problems (serving), so shape
-        # and static args belong in it, not just the bucket dims; the loss
-        # name rides at the END so positional readers stay valid
-        if fshard is not None:
-            key = ("sgl-feat", fshard.n_shards, N, p, G, str(X.dtype),
-                   max_iter, check_every, fmesh is not None, p_b,
-                   sub_spec.num_groups, sub_spec.max_size, len2, loss.name)
-        else:
-            key = ("sgl", N, p, G, str(X.dtype), max_iter, check_every,
-                   pallas, p_b, sub_spec.num_groups, sub_spec.max_size, len2,
-                   loss.name)
-        if key not in seen_keys:
-            seen_keys.add(key)
-            stats.n_compilations += 1
-        if fshard is not None:
-            betas_b, thetas_b, cthetas_b, good_b, iters_b = _feat_sweep(
-                "sgl", fops, max_iter, check_every)(
-                    Xs, X_sub, y, specs_s, sub_spec, alpha, L_sub,
-                    jnp.asarray(lam_pad, X.dtype), jnp.asarray(valid),
-                    jnp.asarray(beta0), tol, gap_scale)
-        else:
-            betas_b, thetas_b, cthetas_b, good_b, iters_b = _sweep_sgl(
-                X, X_sub, y, spec, sub_spec, alpha, L_sub,
-                jnp.asarray(lam_pad, X.dtype), jnp.asarray(valid),
-                jnp.asarray(beta0), tol, gap_scale, max_iter=max_iter,
-                check_every=check_every, use_pallas=pallas, loss=loss)
-        good_np = np.asarray(good_b[:m])     # one host sync
-        k = int(np.argmin(good_np)) if not good_np.all() else m
-        if k == 0:
-            # row 0 (solved on a provably safe set) stopped at max_iter:
-            # keep its best iterate so the path progresses, flagged
-            k = 1
-            stats.n_uncertified += 1
-        stats.n_rejected += int(m - k)
-        theta_bar = thetas_b[k - 1]
-        if fshard is not None:
-            c_prev_s = cthetas_b[k - 1]
-            c_prev = fshard.unshard_features(np.asarray(c_prev_s))
-        else:
-            c_prev = cthetas_b[k - 1]
-        betas_np = np.asarray(betas_b[:k])
-        iters_np = np.asarray(iters_b[:k])
-        jax.block_until_ready(theta_bar)
-        solve_time += time.perf_counter() - ts
-
-        chunk_rows = np.zeros((k, p))
-        chunk_rows[:, col_idx] = betas_np[:, :len(col_idx)]
-        betas[j:j + k] = chunk_rows
-        iters[j:j + k] = iters_np
-        kept_feat[j:j + k] = len(col_idx)       # columns entering the solver
-        kept_grp[j:j + k] = len(np.unique(gid[S]))
-        beta_full = chunk_rows[-1]
-        beta_dev = jnp.asarray(beta_full, X.dtype)
-        lam_bar = float(lam_chunk[k - 1])
-        stats.n_segments += 1
-        stats.buckets.append((p_b, g_b, m, k))
-        spec_m = min(2 * spec_m, 64) if k == m else max(2, k)
-        j += k
-
+    # the timers are filled from the spans (``_path_verb``)
     return PathResult(lambdas=lambdas, betas=betas, lam_max=lam_max,
-                      screen_time=screen_time, solve_time=solve_time,
-                      setup_time=setup_time, iters=iters,
-                      kept_features=kept_feat, kept_groups=kept_grp,
-                      stats=stats)
+                      screen_time=0.0, solve_time=0.0, setup_time=0.0,
+                      iters=iters, kept_features=kept_feat,
+                      kept_groups=kept_grp, stats=stats)
 
 
 # ---------------------------------------------------------------------------
 # Nonnegative Lasso
 # ---------------------------------------------------------------------------
 
+@_path_verb
 def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                           min_ratio: float = 0.01, screen: str = "dpc",
                           tol=1e-9, max_iter: int = 20000,
@@ -844,9 +916,10 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                           compile_keys: Optional[set] = None) -> PathResult:
     """Batched nonnegative-Lasso path: whole-grid DPC / Gap-Safe rules,
     speculative bucketed sweeps with in-scan certification.
-    ``feature_shards`` / ``compile_keys`` as in ``sgl_path_batched`` (the
-    nn partition is singleton-column: equal blocks when the shard count
-    divides p, degraded otherwise)."""
+    ``feature_shards`` / ``compile_keys`` and the spans as in
+    ``sgl_path_batched`` (no ``setup.group_norms``; ``margin_fill_nn`` runs
+    in ``segment.expand``; the nn partition is singleton-column: equal
+    blocks when the shard count divides p, degraded otherwise)."""
     if screen not in ("dpc", "gapsafe", "none"):
         raise ValueError(f"unknown screen mode {screen!r}")
     X = jnp.asarray(X)
@@ -861,32 +934,36 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
             fshard = plan_fs
     pallas = _pallas_active(use_pallas, X.dtype) and fshard is None
 
-    t0 = time.perf_counter()
-    if fshard is not None:
-        fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
-        fops = _fs.feature_ops(fshard.n_shards, fmesh)
-        Xs = fops.place(fshard.stack_columns(np.asarray(X)))
-        xty_s = _fs.sharded_xtv(fops, Xs, y)
-        xty_np = fshard.unshard_features(np.asarray(xty_s))
-        xty = jnp.asarray(xty_np)
-        lam_max, i_star = lambda_max_nn(xty)
-        lam_max = float(lam_max)
-        col_n_s = _fs.sharded_column_norms(fops, Xs)
-        # Theorem-21 boundary normal is the argmax COLUMN — host gather
-        x_star = jnp.asarray(np.asarray(X)[:, int(i_star)])
-        L_full = None
-        jax.block_until_ready((col_n_s, x_star))
-    else:
-        xty = mm(X.T, y)
-        lam_max, i_star = lambda_max_nn(xty)
-        lam_max = float(lam_max)
-        col_n = column_norms(X)
-        L_full = spectral_norm(X) ** 2
-        jax.block_until_ready((col_n, L_full))
-    if lam_max <= 0:
-        raise ValueError("max_i <x_i, y> <= 0: nonnegative Lasso solution is "
-                         "identically zero for every lambda > 0")
-    setup_time = time.perf_counter() - t0
+    with spans.span("setup"):
+        if fshard is not None:
+            fmesh = _fs.resolve_feature_mesh(fshard.n_shards)
+            fops = _fs.feature_ops(fshard.n_shards, fmesh)
+            Xs = fops.place(fshard.stack_columns(np.asarray(X)))
+            with spans.span("setup.xty"):
+                xty_s = _fs.sharded_xtv(fops, Xs, y)
+                xty_np = fshard.unshard_features(np.asarray(xty_s))
+                xty = jnp.asarray(xty_np)
+                lam_max, i_star = lambda_max_nn(xty)
+                lam_max = float(lam_max)
+            with spans.span("setup.col_norms"):
+                col_n_s = jax.block_until_ready(
+                    _fs.sharded_column_norms(fops, Xs))
+            # Theorem-21 boundary normal is the argmax COLUMN — host gather
+            x_star = jnp.asarray(np.asarray(X)[:, int(i_star)])
+            L_full = None
+        else:
+            with spans.span("setup.xty"):
+                xty = mm(X.T, y)
+                lam_max, i_star = lambda_max_nn(xty)
+                lam_max = float(lam_max)
+            with spans.span("setup.col_norms"):
+                col_n = jax.block_until_ready(column_norms(X))
+            with spans.span("setup.spectral_norm"):
+                L_full = jax.block_until_ready(spectral_norm(X) ** 2)
+        if lam_max <= 0:
+            raise ValueError("max_i <x_i, y> <= 0: nonnegative Lasso "
+                             "solution is identically zero for every "
+                             "lambda > 0")
 
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max, n_lambdas, min_ratio)
@@ -897,10 +974,9 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
     iters = np.zeros(J, dtype=np.int64)
     kept_feat = np.zeros(J, dtype=np.int64)
     stats = EngineStats()
-    screen_time = 0.0
-    solve_time = 0.0
-    X_np = np.asarray(X)
-    gap_scale = max(float(0.5 * jnp.vdot(y, y)), 1e-30)
+    with spans.span("host_copy"):
+        X_np = _pull(X)
+        gap_scale = max(float(0.5 * jnp.vdot(y, y)), 1e-30)
 
     theta_bar = y / lam_max
     if fshard is not None:
@@ -919,139 +995,147 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
         j += 1
 
     while j < J:
-        rem, L_rem = _pad_grid(lambdas[j:], X.dtype)
-        ts = time.perf_counter()
-        if screen == "none":
-            fk_np = np.ones((J - j, p), dtype=bool)
-        elif fshard is not None:
-            at_max = lam_bar >= lam_max * (1.0 - 1e-12)
-            n_vec = x_star if at_max else (y / lam_bar - theta_bar)
-            fk_s, _ = _dpc_feat_jit(fops, Xs, y, rem, theta_bar, n_vec,
-                                    col_n_s, safety=safety)
-            if screen == "gapsafe":
-                beta_s = jnp.asarray(fshard.shard_features(
-                    beta_full.astype(X_np.dtype)))
-                resid = y - _fs.sharded_fit(fops, Xs, beta_s)
-                pen = jnp.sum(beta_dev)          # beta >= 0 => l1 = sum
-                radii = _gap_safe_radii_jit(y, rem, theta_bar, resid,
-                                            pen) * (1.0 + safety)
-                fk_s = fk_s & _gap_safe_nn_feat_jit(fops, c_prev_s, radii,
-                                                    col_n_s)
-            fk_np = fshard.unshard_features(np.asarray(fk_s))[:L_rem]
-            stats.n_screens += 1
-        else:
-            n_vec = normal_vector_nn(X, y, lam_bar, lam_max, theta_bar,
-                                     i_star)
-            fk, _ = _dpc_grid_jit(X, y, rem, theta_bar, n_vec, col_n,
-                                  safety=safety, use_pallas=pallas)
-            if screen == "gapsafe":
-                resid = y - mm(X, beta_dev)
-                pen = jnp.sum(beta_dev)          # beta >= 0 => l1 = sum
-                radii = _gap_safe_radii_jit(y, rem, theta_bar, resid,
-                                            pen) * (1.0 + safety)
-                fk = fk & _gap_safe_nn_jit(c_prev, radii, col_n)
-            fk_np = np.asarray(fk)[:L_rem]
-            stats.n_screens += 1
-            stats.n_pallas_screens += int(pallas)
-        screen_time += time.perf_counter() - ts
+        with spans.span("segment"):
+            rem, L_rem = _pad_grid(lambdas[j:], X.dtype)
+            with spans.span("segment.screen"):
+                if screen == "none":
+                    fk_np = np.ones((J - j, p), dtype=bool)
+                elif fshard is not None:
+                    at_max = lam_bar >= lam_max * (1.0 - 1e-12)
+                    n_vec = x_star if at_max else (y / lam_bar - theta_bar)
+                    fk_s, _ = _dpc_feat_jit(fops, Xs, y, rem, theta_bar,
+                                            n_vec, col_n_s, safety=safety)
+                    if screen == "gapsafe":
+                        beta_s = _put(fshard.shard_features(
+                            beta_full.astype(X_np.dtype)))
+                        resid = y - _fs.sharded_fit(fops, Xs, beta_s)
+                        pen = jnp.sum(beta_dev)      # beta >= 0 => l1 = sum
+                        radii = _gap_safe_radii_jit(
+                            y, rem, theta_bar, resid, pen) * (1.0 + safety)
+                        fk_s = fk_s & _gap_safe_nn_feat_jit(
+                            fops, c_prev_s, radii, col_n_s)
+                    fk_np = fshard.unshard_features(_pull(fk_s))[:L_rem]
+                    stats.n_screens += 1
+                else:
+                    n_vec = normal_vector_nn(X, y, lam_bar, lam_max,
+                                             theta_bar, i_star)
+                    fk, _ = _dpc_grid_jit(X, y, rem, theta_bar, n_vec,
+                                          col_n, safety=safety,
+                                          use_pallas=pallas)
+                    if screen == "gapsafe":
+                        resid = y - mm(X, beta_dev)
+                        pen = jnp.sum(beta_dev)      # beta >= 0 => l1 = sum
+                        radii = _gap_safe_radii_jit(
+                            y, rem, theta_bar, resid, pen) * (1.0 + safety)
+                        fk = fk & _gap_safe_nn_jit(c_prev, radii, col_n)
+                    fk_np = _pull(fk)[:L_rem]
+                    stats.n_screens += 1
+                    stats.n_pallas_screens += int(pallas)
 
-        row_counts = fk_np.sum(axis=1)
-        if row_counts[0] == 0:
-            k = (int(np.argmax(row_counts > 0)) if row_counts.any()
-                 else len(row_counts))
-            lam_bar = float(lambdas[j + k - 1])
-            theta_bar = y / lam_bar
-            if fshard is not None:
-                c_prev_s = xty_s / lam_bar
-                c_prev = xty_np / lam_bar
-            else:
-                c_prev = xty / lam_bar
-            beta_dev = jnp.zeros(p, X.dtype)
-            beta_full = np.zeros(p)
+            with spans.span("segment.expand"):
+                row_counts = fk_np.sum(axis=1)
+                if row_counts[0] == 0:
+                    k = (int(np.argmax(row_counts > 0)) if row_counts.any()
+                         else len(row_counts))
+                    lam_bar = float(lambdas[j + k - 1])
+                    theta_bar = y / lam_bar
+                    if fshard is not None:
+                        c_prev_s = xty_s / lam_bar
+                        c_prev = xty_np / lam_bar
+                    else:
+                        c_prev = xty / lam_bar
+                    beta_dev = jnp.zeros(p, X.dtype)
+                    beta_full = np.zeros(p)
+                    j += k
+                    continue
+                base = fk_np[0]
+                n_base = int(base.sum())
+                p_b = _feature_bucket(n_base, p, min_bucket, margin)
+                S = _expand_set(base, fk_np, p_b)
+                margin_fill_nn(S, _pull(c_prev), p_b)
+
+            m = min(J - j, spec_m)
+
+            with spans.span("segment.gather"):
+                if S.all():
+                    col_idx = np.arange(p)
+                    if L_full is None:
+                        L_full = jax.block_until_ready(
+                            spectral_norm(X) ** 2)
+                    X_sub, L_sub = X, L_full
+                    p_b = p
+                else:
+                    col_idx = np.nonzero(S)[0]
+                    X_s = np.zeros((N, p_b), dtype=X_np.dtype)
+                    X_s[:, :len(col_idx)] = X_np[:, col_idx]
+                    X_sub = _put(X_s)
+                    L_sub = jax.block_until_ready(
+                        spectral_norm(X_sub, iters=25) ** 2)
+
+            with spans.span("segment.sweep"):
+                beta0 = np.zeros(p_b, dtype=X_np.dtype)
+                beta0[:len(col_idx)] = beta_full[col_idx]
+                lam_chunk = lambdas[j:j + m]
+                len2 = _pow2_len(m)
+                lam_pad = np.concatenate(
+                    [lam_chunk, np.full(len2 - m, lam_chunk[-1])])
+                valid = np.arange(len2) < m
+                if fshard is not None:
+                    key = ("nn-feat", fshard.n_shards, N, p, str(X.dtype),
+                           max_iter, check_every, fmesh is not None, p_b,
+                           len2, "squared")
+                else:
+                    key = ("nn", N, p, str(X.dtype), max_iter, check_every,
+                           pallas, p_b, len2, "squared")
+                if key not in seen_keys:
+                    seen_keys.add(key)
+                    stats.n_compilations += 1
+                lam_d = _put(lam_pad, X.dtype)
+                valid_d, beta0_d = _put(valid), _put(beta0)
+                if fshard is not None:
+                    betas_b, thetas_b, cthetas_b, good_b, iters_b = \
+                        _feat_sweep("nn", fops, max_iter, check_every)(
+                            Xs, X_sub, y, L_sub, lam_d, valid_d, beta0_d,
+                            tol, gap_scale)
+                else:
+                    betas_b, thetas_b, cthetas_b, good_b, iters_b = \
+                        _sweep_nn(
+                            X, X_sub, y, L_sub, lam_d, valid_d, beta0_d,
+                            tol, gap_scale, max_iter=max_iter,
+                            check_every=check_every, use_pallas=pallas)
+                good_np = _pull(good_b[:m])
+                k = int(np.argmin(good_np)) if not good_np.all() else m
+                if k == 0:
+                    k = 1
+                    stats.n_uncertified += 1
+                stats.n_rejected += int(m - k)
+                theta_bar = thetas_b[k - 1]
+                if fshard is not None:
+                    c_prev_s = cthetas_b[k - 1]
+                    c_prev = fshard.unshard_features(_pull(c_prev_s))
+                else:
+                    c_prev = cthetas_b[k - 1]
+                betas_np = _pull(betas_b[:k])
+                iters_np = _pull(iters_b[:k])
+                jax.block_until_ready(theta_bar)
+                spans.add("rows_solved", m)
+                spans.add("rows_accepted", k)
+
+            with spans.span("segment.assemble"):
+                chunk_rows = np.zeros((k, p))
+                chunk_rows[:, col_idx] = betas_np[:, :len(col_idx)]
+                betas[j:j + k] = chunk_rows
+                iters[j:j + k] = iters_np
+                kept_feat[j:j + k] = len(col_idx)   # columns in the solver
+                beta_full = chunk_rows[-1]
+                beta_dev = _put(beta_full, X.dtype)
+            lam_bar = float(lam_chunk[k - 1])
+            stats.n_segments += 1
+            stats.buckets.append((p_b, 0, m, k))
+            spec_m = min(2 * spec_m, 64) if k == m else max(2, k)
             j += k
-            continue
 
-        base = fk_np[0]
-        n_base = int(base.sum())
-        p_b = _feature_bucket(n_base, p, min_bucket, margin)
-        S = _expand_set(base, fk_np, p_b)
-        margin_fill_nn(S, np.asarray(c_prev), p_b)
-
-        m = min(J - j, spec_m)
-
-        ts = time.perf_counter()
-        if S.all():
-            col_idx = np.arange(p)
-            if L_full is None:
-                L_full = spectral_norm(X) ** 2
-            X_sub, L_sub = X, L_full
-            p_b = p
-        else:
-            col_idx = np.nonzero(S)[0]
-            X_s = np.zeros((N, p_b), dtype=X_np.dtype)
-            X_s[:, :len(col_idx)] = X_np[:, col_idx]
-            X_sub = jnp.asarray(X_s)
-            L_sub = spectral_norm(X_sub, iters=25) ** 2
-        beta0 = np.zeros(p_b, dtype=X_np.dtype)
-        beta0[:len(col_idx)] = beta_full[col_idx]
-
-        lam_chunk = lambdas[j:j + m]
-        len2 = _pow2_len(m)
-        lam_pad = np.concatenate(
-            [lam_chunk, np.full(len2 - m, lam_chunk[-1])])
-        valid = np.arange(len2) < m
-        if fshard is not None:
-            key = ("nn-feat", fshard.n_shards, N, p, str(X.dtype),
-                   max_iter, check_every, fmesh is not None, p_b, len2,
-                   "squared")
-        else:
-            key = ("nn", N, p, str(X.dtype), max_iter, check_every, pallas,
-                   p_b, len2, "squared")
-        if key not in seen_keys:
-            seen_keys.add(key)
-            stats.n_compilations += 1
-        if fshard is not None:
-            betas_b, thetas_b, cthetas_b, good_b, iters_b = _feat_sweep(
-                "nn", fops, max_iter, check_every)(
-                    Xs, X_sub, y, L_sub, jnp.asarray(lam_pad, X.dtype),
-                    jnp.asarray(valid), jnp.asarray(beta0), tol, gap_scale)
-        else:
-            betas_b, thetas_b, cthetas_b, good_b, iters_b = _sweep_nn(
-                X, X_sub, y, L_sub, jnp.asarray(lam_pad, X.dtype),
-                jnp.asarray(valid), jnp.asarray(beta0), tol, gap_scale,
-                max_iter=max_iter, check_every=check_every,
-                use_pallas=pallas)
-        good_np = np.asarray(good_b[:m])
-        k = int(np.argmin(good_np)) if not good_np.all() else m
-        if k == 0:
-            k = 1
-            stats.n_uncertified += 1
-        stats.n_rejected += int(m - k)
-        theta_bar = thetas_b[k - 1]
-        if fshard is not None:
-            c_prev_s = cthetas_b[k - 1]
-            c_prev = fshard.unshard_features(np.asarray(c_prev_s))
-        else:
-            c_prev = cthetas_b[k - 1]
-        betas_np = np.asarray(betas_b[:k])
-        iters_np = np.asarray(iters_b[:k])
-        jax.block_until_ready(theta_bar)
-        solve_time += time.perf_counter() - ts
-
-        chunk_rows = np.zeros((k, p))
-        chunk_rows[:, col_idx] = betas_np[:, :len(col_idx)]
-        betas[j:j + k] = chunk_rows
-        iters[j:j + k] = iters_np
-        kept_feat[j:j + k] = len(col_idx)       # columns entering the solver
-        beta_full = chunk_rows[-1]
-        beta_dev = jnp.asarray(beta_full, X.dtype)
-        lam_bar = float(lam_chunk[k - 1])
-        stats.n_segments += 1
-        stats.buckets.append((p_b, 0, m, k))
-        spec_m = min(2 * spec_m, 64) if k == m else max(2, k)
-        j += k
-
+    # the timers are filled from the spans (``_path_verb``)
     return PathResult(lambdas=lambdas, betas=betas, lam_max=lam_max,
-                      screen_time=screen_time, solve_time=solve_time,
-                      setup_time=setup_time, iters=iters,
-                      kept_features=kept_feat, stats=stats)
+                      screen_time=0.0, solve_time=0.0, setup_time=0.0,
+                      iters=iters, kept_features=kept_feat, stats=stats)

@@ -46,6 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import spans
 from .cv import (CVResult, EngineStats, FoldState, StabilityResult,
                  _cv_statistics, _masks_from_folds, kfold_indices,
                  nn_fold_paths, per_fold_centering, sgl_fold_paths,
@@ -141,13 +142,18 @@ class SGLSession:
         self.compile_keys: set = set()   # persistent sweep-shape cache
         self.stats = EngineStats()       # aggregate over the session
         self._lam_max_cache: dict = {}   # grid-anchor cache (see lambda_max)
-        if problem.loss == "squared":
-            self._xty = mm(problem.X.T, problem.y)
-        else:
-            # the grid anchor correlates X with the gradient of the loss at
-            # beta = 0 (y for squared; y - 1/2 for logistic)
-            self._xty = mm(problem.X.T, get_loss(
-                problem.loss).residual_at_zero(problem.y))
+        with spans.span("session.init"):
+            if problem.loss == "squared":
+                self._xty = mm(problem.X.T, problem.y)
+            else:
+                # the grid anchor correlates X with the gradient of the
+                # loss at beta = 0 (y for squared; y - 1/2 for logistic)
+                self._xty = mm(problem.X.T, get_loss(
+                    problem.loss).residual_at_zero(problem.y))
+            # ends the span at the device work; it also frees this GEMV's
+            # transposed copy of X before an engine call makes its own, so
+            # at most one such copy is live at a time
+            jax.block_until_ready(self._xty)
         self._last_cv: Optional[_CVState] = None
 
     # ---- plumbing ---------------------------------------------------------
