@@ -73,7 +73,7 @@ from .fenchel import shrink, sgl_penalty, weighted_l1
 from .groups import GroupSpec, group_norms, pad_slots
 from .lambda_max import dual_scaling_sgl, lambda_max_sgl
 from .linalg import (column_norms, group_frobenius_norms,
-                     group_spectral_norms, mm, spectral_norm)
+                     gram_groups, group_spectral_norms, mm, spectral_norm)
 from .losses import SQUARED, Loss, get_loss
 from .path import PathResult, _bucket, default_lambda_grid
 from .screening import (gap_safe_grid_radii, gap_safe_grid_radii_loss,
@@ -581,7 +581,9 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     ``segment`` per loop pass (> ``segment.screen``, ``segment.expand``,
     ``segment.gather``, ``segment.sweep``, ``segment.assemble``), with the
     array bytes each moves between host and device (``h2d_bytes``,
-    ``d2h_bytes``) and the sweep's ``rows_solved`` and ``rows_accepted``.
+    ``d2h_bytes``), the sweep's ``rows_solved`` and ``rows_accepted``, and
+    on ``setup.group_norms`` the ``gram_groups`` whose norms came from
+    banded Gram blocks (``linalg.gram_groups``).
     """
     if screen not in ("tlfre", "gapsafe", "none"):
         raise ValueError(f"unknown screen mode {screen!r}")
@@ -609,6 +611,8 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
             fshard = plan_fs
     pallas = (_pallas_active(use_pallas, X.dtype) and fshard is None
               and squared and spec.feature_weights is None)
+    # groups whose spectral norms come from banded Gram blocks (linalg)
+    n_gram = gram_groups(X, spec) if specnorm_method == "power" else 0
 
     with spans.span("setup"):
         if fshard is not None:
@@ -626,6 +630,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
                 col_n_s = jax.block_until_ready(
                     _fs.sharded_column_norms(fops, Xs))
             with spans.span("setup.group_norms"):
+                spans.add("gram_groups", n_gram)
                 if specnorm_method == "power":
                     gspec_s = _fs.sharded_group_spectral_norms(fops, Xs,
                                                                specs_s)
@@ -654,6 +659,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
             with spans.span("setup.col_norms"):
                 col_n = jax.block_until_ready(column_norms(X))
             with spans.span("setup.group_norms"):
+                spans.add("gram_groups", n_gram)
                 if specnorm_method == "power":
                     gspec = group_spectral_norms(X, spec)
                 else:

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 import jax
 
-from repro.core import GroupSpec, Plan, Problem, SGLSession, spans
+from repro.core import GroupSpec, Plan, Problem, SGLSession, linalg, spans
 from repro.core.path_engine import nn_lasso_path_batched, sgl_path_batched
 
 SEGMENT_PARTS = ("segment.screen", "segment.expand", "segment.gather",
@@ -98,6 +98,26 @@ def test_sweep_and_transfer_counters(engine):
     for s in rec:
         if s.name == "segment.assemble":
             assert s.counters == {"h2d_bytes": p * item}
+
+
+@pytest.mark.parametrize("case", ["ragged", "frobenius", "wide"])
+def test_group_norms_count_the_gram_groups(case):
+    rng = np.random.default_rng(5)
+    sizes = rng.integers(1, 9, 25)
+    sizes[::4] = 1
+    if case == "wide":                     # one group above GRAM_MAX_SIZE
+        sizes[3] = linalg.GRAM_MAX_SIZE + 2
+    spec = GroupSpec.from_sizes(sizes)
+    X = rng.standard_normal((400, spec.num_features))
+    y = X[:, :4] @ np.ones(4) + 0.05 * rng.standard_normal(400)
+    res = sgl_path_batched(
+        X, y, spec, 1.0, n_lambdas=6, min_ratio=0.3, tol=1e-8,
+        specnorm_method="frobenius" if case == "frobenius" else "power")
+    (norms,) = [s for s in res.spans if s.name == "setup.group_norms"]
+    # only the wide group runs the per-group iteration
+    n_gram = {"ragged": spec.num_groups, "frobenius": 0,
+              "wide": spec.num_groups - 1}[case]
+    assert norms.counters == {"gram_groups": n_gram}
 
 
 def test_compiles_land_on_the_sweep_of_a_fresh_shape():

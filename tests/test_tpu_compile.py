@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e at the paper's real sizes.
+"""The Pallas kernels, and the engine's per-group spectral norms, compile
+for a TPU v5e at the paper's real sizes.
 
 Interpret mode (every other kernel test) never applies the TPU's block
 tiling rule or its memory limits; Mosaic does, when it compiles for a chip.
@@ -102,3 +103,26 @@ def test_dpc_screen_folds_compiles_at_table3(shape, L):
     held, _ = _compile(ops.dpc_screen_folds, shape(K, L, DPC_P),
                        shape(K, L), shape(K, DPC_P))
     assert held <= 3 * 4 * K * L * DPC_P + np.prod((K, DPC_P)) * 4 * 2
+
+
+def test_group_spectral_norms_compile_at_adni(topo):
+    """The banded Gram route fuses its shifted products into reductions
+    over X on the chip's compiler too: its temporaries stay a few percent
+    of X's 1.27 GB, where one copy of X would raise ``peak_hbm_gb``."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import GroupSpec, group_spectral_norms
+    from repro.core.linalg import gram_groups
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    sizes = np.full(ADNI_G, 4)
+    sizes[:23_490] = 6                   # 426,040 columns, ragged
+    sizes[0], sizes[1] = ADNI_NMAX, 2
+    spec = GroupSpec.from_sizes(sizes)
+    assert spec.num_features == ADNI_P
+    spec = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        spec)
+    X = jax.ShapeDtypeStruct((ADNI_N, ADNI_P), jnp.float32,
+                             sharding=one_chip)
+    assert gram_groups(X, spec) == ADNI_G
+    m = group_spectral_norms.lower(X, spec).compile().memory_analysis()
+    assert m.temp_size_in_bytes < 0.05 * 4 * ADNI_N * ADNI_P
