@@ -229,7 +229,8 @@ def _entries():
     def sweep_nn(dtype):
         r = _rep(dtype)
         fn = functools.partial(sweep_nn_core, **sweep_kw)
-        return fn, [r["X"], r["X_sub"], r["y"], r["lip"], r["lams"],
+        cols = jnp.arange(r["X_sub"].shape[1], dtype=jnp.int32)
+        return fn, [r["X"], r["X_sub"], cols, r["y"], r["lip"], r["lams"],
                     r["valid"], r["beta0"], 1e-9, 1.0]
 
     def fold_sweep_sgl(dtype, centered):
@@ -248,8 +249,12 @@ def _entries():
         r = _fold_rep(dtype)
         fn = jax.vmap(functools.partial(sweep_nn_core, **sweep_kw),
                       in_axes=_cv._NN_SWEEP_AXES)
-        return fn, [r["X"], r["X_subs"], r["Y"], r["lips"], r["lamss"],
-                    r["valids"], r["beta0s"], 1e-9, r["gap_scales"]]
+        cols = jnp.broadcast_to(
+            jnp.arange(r["X_subs"].shape[2], dtype=jnp.int32),
+            r["X_subs"].shape[::2])
+        return fn, [r["X"], r["X_subs"], cols, r["Y"], r["lips"],
+                    r["lamss"], r["valids"], r["beta0s"], 1e-9,
+                    r["gap_scales"]]
 
     def screen_folds_sgl(dtype, centered):
         r = _fold_rep(dtype)

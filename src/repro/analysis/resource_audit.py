@@ -315,11 +315,11 @@ def _args_for_key(key: tuple):
         dt = jnp.dtype(dtype_s)
         fn = functools.partial(sweep_nn_core, max_iter=max_iter,
                                check_every=check_every, use_pallas=pallas)
-        args = [S((N, p), dt), S((N, p_b), dt), S((N,), dt), S((), dt),
-                S((len2,), dt), S((len2,), jnp.bool_), S((p_b,), dt),
-                1e-9, 1.0]
-        resident = [True, False, True, False, False, False, False, False,
-                    False]
+        args = [S((N, p), dt), S((N, p_b), dt), S((p_b,), jnp.int32),
+                S((N,), dt), S((), dt), S((len2,), dt),
+                S((len2,), jnp.bool_), S((p_b,), dt), 1e-9, 1.0]
+        resident = [True, False, False, True, False, False, False, False,
+                    False, False]
         return fn, args, resident
     if kind == "sgl-folds":
         (_, Ka, N, p, G, dtype_s, max_iter, check_every, _mesh,
@@ -351,11 +351,12 @@ def _args_for_key(key: tuple):
         core = functools.partial(sweep_nn_core, max_iter=max_iter,
                                  check_every=check_every, use_pallas=pallas)
         fn = jax.vmap(core, in_axes=_NN_SWEEP_AXES)
-        args = [S((N, p), dt), S((Ka, N, p_b), dt), S((Ka, N), dt),
-                S((Ka,), dt), S((Ka, len2), dt), S((Ka, len2), jnp.bool_),
+        args = [S((N, p), dt), S((Ka, N, p_b), dt),
+                S((Ka, p_b), jnp.int32), S((Ka, N), dt), S((Ka,), dt),
+                S((Ka, len2), dt), S((Ka, len2), jnp.bool_),
                 S((Ka, p_b), dt), 1e-9, S((Ka,), dt)]
-        resident = [True, False, True, False, False, False, False, False,
-                    False]
+        resident = [True, False, False, True, False, False, False, False,
+                    False, False]
         return fn, args, resident
     if kind in ("sgl-feat", "nn-feat"):
         # PER-DEVICE card: one shard block priced at the static width
@@ -405,11 +406,11 @@ def _feat_trace(key: tuple, ops, S_lead: int):
     p_sh = shard_width_bound(p, p, S_eff, 1)
     fn = functools.partial(sweep_nn_core_feat, ops=ops, max_iter=max_iter,
                            check_every=check_every)
-    args = [S((S_lead, N, p_sh), dt), S((N, p_b), dt), S((N,), dt),
-            S((), dt), S((len2,), dt), S((len2,), jnp.bool_), S((p_b,), dt),
-            1e-9, 1.0]
-    resident = [True, False, True, False, False, False, False, False,
-                False]
+    args = [S((S_lead, N, p_sh), dt), S((N, p_b), dt),
+            S((p_b,), jnp.int32), S((N,), dt), S((), dt), S((len2,), dt),
+            S((len2,), jnp.bool_), S((p_b,), dt), 1e-9, 1.0]
+    resident = [True, False, False, True, False, False, False, False,
+                False, False]
     return fn, args, resident
 
 

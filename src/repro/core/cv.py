@@ -74,8 +74,9 @@ from .losses import SQUARED, Loss, get_loss
 from .linalg import group_spectral_norms, mm, spectral_norm
 from .path import _bucket
 from .path_engine import (EngineStats, _expand_set, _feature_bucket,
-                          _pallas_active, _pow2_len, margin_fill_nn,
-                          margin_fill_sgl, sweep_nn_core, sweep_sgl_core)
+                          _pallas_active, _pow2_len, bucket_columns,
+                          margin_fill_nn, margin_fill_sgl, sweep_nn_core,
+                          sweep_sgl_core)
 from .dpc import dpc_screen_grid_folds_feat
 from .screening import (gap_safe_grid_radii, gap_safe_screen_grid_folds,
                         gap_safe_screen_grid_folds_feat,
@@ -325,7 +326,7 @@ def _screen_folds_nn_feat(fops, Xs, Y, rem, lam_bars, lam_maxs, theta_bars,
 # ---------------------------------------------------------------------------
 
 _SGL_SWEEP_AXES = (None, 0, 0, None, 0, None, 0, 0, 0, 0, None, 0)
-_NN_SWEEP_AXES = (None, 0, 0, 0, 0, 0, 0, None, 0)
+_NN_SWEEP_AXES = (None, 0, 0, 0, 0, 0, 0, 0, None, 0)
 _FOLD_SWEEPS: dict = {}
 
 
@@ -901,8 +902,9 @@ class _NNFoldEngine(_FoldEngine):
             k_rows = jnp.asarray(np.asarray([k for k, _, _, _ in cohort]))
             runner = _fold_sweep("nn", self.mesh, Ka, self.max_iter,
                                  self.check_every, use_pallas=self.pallas)
+            cols = np.stack([bucket_columns(c, p_b) for c in col_idxs])
             outputs = runner(
-                X, X_subs_d, self.Y[k_rows], L_subs,
+                X, X_subs_d, jnp.asarray(cols), self.Y[k_rows], L_subs,
                 jnp.asarray(lam_pads, X.dtype), jnp.asarray(valids),
                 jnp.asarray(beta0s), self.tol,
                 jnp.asarray(self.gap_scales[[k for k, _, _, _ in cohort]],

@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from . import spans
 from .dpc import (dpc_screen_grid, dpc_screen_grid_feat, dual_scaling_nn,
                   gap_safe_screen_grid_nn, gap_safe_screen_grid_nn_feat,
-                  lambda_max_nn, normal_vector_nn)
+                  lambda_max_nn, nn_gap, normal_vector_nn)
 from .estimation import normal_vector_sgl
 from .fenchel import shrink, sgl_penalty, weighted_l1
 from .groups import GroupSpec, group_norms, pad_slots
@@ -93,8 +93,9 @@ class EngineStats:
     failed (at most one solved row per segment is wasted; the rest are
     skipped on device).  ``n_uncertified`` counts rows returned WITHOUT a
     full-problem certificate: a segment's row 0 whose gap stayed above
-    ``tol`` (its solve hit ``max_iter``); the engine keeps the best iterate
-    so the path can go on, and the row is not certified.
+    ``tol`` (its solve hit ``max_iter``, or a nonnegative-Lasso row ran out
+    of ``NN_REFINE_ROUNDS``); the engine keeps the best iterate so the path
+    can go on, and the row is not certified.
     ``n_pallas_screens`` counts grid screens that ran
     through the fused Pallas kernels (always 0 on float64 paths — the
     kernels are float32 and ``_pallas_active`` never engages them there).
@@ -284,6 +285,15 @@ def margin_fill_nn(S, c_prev_np, p_b: int):
         S[np.argpartition(-cand, spare - 1)[:spare]] = True
 
 
+def bucket_columns(col_idx, p_b: int) -> np.ndarray:
+    """(p_b,) int32: X's column in each slot of a nonnegative-Lasso bucket.
+    A pad slot repeats the last real column: its coefficient is 0, and it
+    adds no column to the certificate's max over the bucket."""
+    cols = np.full(p_b, col_idx[-1] if len(col_idx) else 0, np.int32)
+    cols[:len(col_idx)] = col_idx
+    return cols
+
+
 # ---------------------------------------------------------------------------
 # Jitted sweeps: lax.scan over a lambda chunk, carry = (beta, alive).
 # Each row certifies itself against the FULL problem right after its solve;
@@ -349,35 +359,60 @@ _sweep_sgl = functools.partial(
         sweep_sgl_core)
 
 
-def sweep_nn_core(X, X_sub, y, lipschitz, lams, valid, beta0, tol,
-                  gap_scale, *, max_iter: int, check_every: int,
-                  use_pallas: bool):
+# FISTA rounds a nonnegative-Lasso row may add, each to half the inner
+# target of the last, when the bucket's own test passed and the full-X
+# certificate did not.  Both read the same gap and differ only where the
+# bucket GEMV and the full-X one round differently.
+NN_REFINE_ROUNDS = 3
+
+
+def _nn_rows(X_sub, y, lipschitz, lams, valid, beta0, tol, gap_scale,
+             certify, c_shape, *, max_iter: int, check_every: int):
+    """Scan of nonnegative-Lasso rows, each solved on the bucket ``X_sub``
+    and certified against the FULL problem by ``certify(rho)``, which
+    returns (X^T rho in its layout, its entries at the bucket's columns,
+    the dual scaling s).  The certificate is ``dpc.nn_gap``; ``max_iter``
+    bounds a row's iterations over all its rounds."""
     N = y.shape[0]
-    p = X.shape[1]
     tol = SQUARED.effective_tol(tol, y.dtype)
+    limit = tol * gap_scale * 1.01
 
     def step(carry, xs):
         beta, alive = carry
         lam, ok = xs
 
-        def run(b):
+        def more(st):
+            _, its, good, closable, _, _, rounds, _ = st
+            return (~good & closable & (its < max_iter)
+                    & (rounds <= NN_REFINE_ROUNDS))
+
+        def solve(st):
+            b, its, _, _, _, _, rounds, inner = st
+            inner = 0.5 * inner
             res = fista_nn_lasso(X_sub, y, lam, lipschitz, b,
-                                 max_iter=max_iter, check_every=check_every,
-                                 tol=tol)
-            resid = y - mm(X_sub, res.beta)
-            rho = resid / lam
-            c = _xtv(X, rho, use_pallas).astype(b.dtype)
-            s = dual_scaling_nn(c)
-            theta = (s * rho).astype(b.dtype)
-            pval = 0.5 * jnp.vdot(resid, resid) + lam * jnp.sum(res.beta)
-            d = y - lam * theta
-            dval = 0.5 * jnp.vdot(y, y) - 0.5 * jnp.vdot(d, d)
-            gap = pval - dval
-            good = gap <= tol * gap_scale * 1.01
-            return res.beta, theta, (s * c).astype(b.dtype), good, res.iters
+                                 max_iter=max_iter - its,
+                                 check_every=check_every, tol=inner)
+            r = y - mm(X_sub, res.beta)
+            rho = r / lam
+            c, c_cols, s = certify(rho)
+            good = nn_gap(r, res.beta, c_cols, lam, s) <= limit
+            # s is set inside the bucket (or is 1): the bucket's test read
+            # this gap up to rounding, so more FISTA can close it
+            closable = jnp.max(c) <= jnp.maximum(jnp.max(c_cols), 1.0)
+            return (res.beta, its + res.iters, good, closable,
+                    (s * rho).astype(b.dtype), (s * c).astype(b.dtype),
+                    rounds + 1, inner)
+
+        def run(b):
+            st = (b, jnp.asarray(0), jnp.asarray(False), jnp.asarray(True),
+                  jnp.zeros(N, b.dtype), jnp.zeros(c_shape, b.dtype),
+                  jnp.asarray(0), 2.0 * tol)
+            b, its, good, _, theta, ctheta, _, _ = jax.lax.while_loop(
+                more, solve, st)
+            return b, theta, ctheta, good, its
 
         def skip(b):
-            return (b, jnp.zeros(N, b.dtype), jnp.zeros(p, b.dtype),
+            return (b, jnp.zeros(N, b.dtype), jnp.zeros(c_shape, b.dtype),
                     jnp.asarray(False), jnp.asarray(0))
 
         beta_new, theta, ctheta, good, its = jax.lax.cond(
@@ -386,7 +421,23 @@ def sweep_nn_core(X, X_sub, y, lipschitz, lams, valid, beta0, tol,
 
     _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
                           (lams, valid))
-    return out
+    return out   # (betas, thetas, cthetas, good, iters)
+
+
+def sweep_nn_core(X, X_sub, cols, y, lipschitz, lams, valid, beta0, tol,
+                  gap_scale, *, max_iter: int, check_every: int,
+                  use_pallas: bool):
+    """``cols`` (p_b,) int: X's column in each bucket slot (a pad slot
+    repeats a real one)."""
+    dtype = beta0.dtype
+
+    def certify(rho):
+        c = _xtv(X, rho, use_pallas).astype(dtype)
+        return c, c[cols], dual_scaling_nn(c)
+
+    return _nn_rows(X_sub, y, lipschitz, lams, valid, beta0, tol,
+                    gap_scale, certify, (X.shape[1],), max_iter=max_iter,
+                    check_every=check_every)
 
 
 _sweep_nn = functools.partial(
@@ -451,46 +502,22 @@ def sweep_sgl_core_feat(Xs, X_sub, y, specs, sub_spec: GroupSpec, alpha,
     return out   # (betas, thetas, cthetas (m, S, p_shard), good, iters)
 
 
-def sweep_nn_core_feat(Xs, X_sub, y, lipschitz, lams, valid, beta0, tol,
-                       gap_scale, *, ops, max_iter: int, check_every: int):
+def sweep_nn_core_feat(Xs, X_sub, cols, y, lipschitz, lams, valid, beta0,
+                       tol, gap_scale, *, ops, max_iter: int,
+                       check_every: int):
+    """``cols`` (p_b,) int: each bucket slot's position in the flattened
+    (S * p_shard) stacked layout (``FeatureShardPlan.stacked_index``)."""
     from ..distributed.feature_shard import cert_nn
-    N = y.shape[0]
-    S_n, _, p_sh = Xs.shape
-    tol = SQUARED.effective_tol(tol, y.dtype)
+    dtype = beta0.dtype
 
-    def step(carry, xs):
-        beta, alive = carry
-        lam, ok = xs
+    def certify(rho):
+        c_s, s = cert_nn(ops, Xs, rho)
+        c_s = c_s.astype(dtype)
+        return c_s, c_s.reshape(-1)[cols], s
 
-        def run(b):
-            res = fista_nn_lasso(X_sub, y, lam, lipschitz, b,
-                                 max_iter=max_iter, check_every=check_every,
-                                 tol=tol)
-            resid = y - mm(X_sub, res.beta)
-            rho = resid / lam
-            c_s, s = cert_nn(ops, Xs, rho)
-            c_s = c_s.astype(b.dtype)
-            theta = (s * rho).astype(b.dtype)
-            pval = 0.5 * jnp.vdot(resid, resid) + lam * jnp.sum(res.beta)
-            d = y - lam * theta
-            dval = 0.5 * jnp.vdot(y, y) - 0.5 * jnp.vdot(d, d)
-            gap = pval - dval
-            good = gap <= tol * gap_scale * 1.01
-            return (res.beta, theta, (s * c_s).astype(b.dtype), good,
-                    res.iters)
-
-        def skip(b):
-            return (b, jnp.zeros(N, b.dtype),
-                    jnp.zeros((S_n, p_sh), b.dtype),
-                    jnp.asarray(False), jnp.asarray(0))
-
-        beta_new, theta, ctheta, good, its = jax.lax.cond(
-            alive & ok, run, skip, beta)
-        return (beta_new, alive & good), (beta_new, theta, ctheta, good, its)
-
-    _, out = jax.lax.scan(step, (beta0, jnp.asarray(True)),
-                          (lams, valid))
-    return out
+    return _nn_rows(X_sub, y, lipschitz, lams, valid, beta0, tol,
+                    gap_scale, certify, (Xs.shape[0], Xs.shape[2]),
+                    max_iter=max_iter, check_every=check_every)
 
 
 # jit cache for the sharded sweeps: ``ops`` (executor + mesh) is baked in
@@ -581,7 +608,8 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     ``segment`` per loop pass (> ``segment.screen``, ``segment.expand``,
     ``segment.gather``, ``segment.sweep``, ``segment.assemble``), with the
     array bytes each moves between host and device (``h2d_bytes``,
-    ``d2h_bytes``), the sweep's ``rows_solved`` and ``rows_accepted``, and
+    ``d2h_bytes``), the sweep's ``rows_solved``, ``rows_accepted`` and
+    ``rows_capped`` (solved rows whose iterations reached ``max_iter``), and
     on ``setup.group_norms`` the ``gram_groups`` whose norms came from
     banded Gram blocks (``linalg.gram_groups``).
     """
@@ -880,10 +908,12 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
                 else:
                     c_prev = cthetas_b[k - 1]
                 betas_np = _pull(betas_b[:k])
-                iters_np = _pull(iters_b[:k])
+                iters_m = _pull(iters_b[:m])
+                iters_np = iters_m[:k]
                 jax.block_until_ready(theta_bar)
                 spans.add("rows_solved", m)
                 spans.add("rows_accepted", k)
+                spans.add("rows_capped", int(np.sum(iters_m >= max_iter)))
 
             with spans.span("segment.assemble"):
                 chunk_rows = np.zeros((k, p))
@@ -999,6 +1029,11 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
     j = 0
     while j < J and lambdas[j] >= lam_max * (1.0 - 1e-12):
         j += 1
+    # every sweep of the path scans the same length: a sweep executable
+    # holds about 0.6 MB of device code (v5e), and a length per chunk
+    # size would make the code a process loads, and so its peak memory,
+    # depend on the responses it has met
+    len2 = _pow2_len(min(J - j, max(64, spec_m)))
 
     while j < J:
         with spans.span("segment"):
@@ -1082,7 +1117,6 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                 beta0 = np.zeros(p_b, dtype=X_np.dtype)
                 beta0[:len(col_idx)] = beta_full[col_idx]
                 lam_chunk = lambdas[j:j + m]
-                len2 = _pow2_len(m)
                 lam_pad = np.concatenate(
                     [lam_chunk, np.full(len2 - m, lam_chunk[-1])])
                 valid = np.arange(len2) < m
@@ -1098,16 +1132,18 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                     stats.n_compilations += 1
                 lam_d = _put(lam_pad, X.dtype)
                 valid_d, beta0_d = _put(valid), _put(beta0)
+                cols = bucket_columns(col_idx, p_b)
                 if fshard is not None:
+                    cols_d = _put(fshard.stacked_index(cols))
                     betas_b, thetas_b, cthetas_b, good_b, iters_b = \
                         _feat_sweep("nn", fops, max_iter, check_every)(
-                            Xs, X_sub, y, L_sub, lam_d, valid_d, beta0_d,
-                            tol, gap_scale)
+                            Xs, X_sub, cols_d, y, L_sub, lam_d, valid_d,
+                            beta0_d, tol, gap_scale)
                 else:
                     betas_b, thetas_b, cthetas_b, good_b, iters_b = \
                         _sweep_nn(
-                            X, X_sub, y, L_sub, lam_d, valid_d, beta0_d,
-                            tol, gap_scale, max_iter=max_iter,
+                            X, X_sub, _put(cols), y, L_sub, lam_d, valid_d,
+                            beta0_d, tol, gap_scale, max_iter=max_iter,
                             check_every=check_every, use_pallas=pallas)
                 good_np = _pull(good_b[:m])
                 k = int(np.argmin(good_np)) if not good_np.all() else m
@@ -1122,10 +1158,12 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                 else:
                     c_prev = cthetas_b[k - 1]
                 betas_np = _pull(betas_b[:k])
-                iters_np = _pull(iters_b[:k])
+                iters_m = _pull(iters_b[:m])
+                iters_np = iters_m[:k]
                 jax.block_until_ready(theta_bar)
                 spans.add("rows_solved", m)
                 spans.add("rows_accepted", k)
+                spans.add("rows_capped", int(np.sum(iters_m >= max_iter)))
 
             with spans.span("segment.assemble"):
                 chunk_rows = np.zeros((k, p))

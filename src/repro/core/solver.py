@@ -149,12 +149,12 @@ def solve_sgl(X, y, spec: GroupSpec, lam, alpha, lipschitz, beta0=None, *,
 # ---------------------------------------------------------------------------
 
 def _nn_gap(X, y, lam, beta):
-    rho = (y - mm(X, beta)) / lam
-    s = _dpc.dual_scaling_nn(mm(X.T, rho))
-    theta = s * rho
-    p = _dpc.nn_primal_objective(X, y, beta, lam)
-    d = _dpc.nn_dual_objective(y, theta, lam)
-    return p, d, theta
+    """(duality gap, feasible dual point) at beta, ``dpc.nn_gap``."""
+    r = y - mm(X, beta)
+    rho = r / lam
+    c = mm(X.T, rho)
+    s = _dpc.dual_scaling_nn(c)
+    return _dpc.nn_gap(r, beta, c, lam, s), s * rho
 
 
 def fista_nn_lasso(X, y, lam, lipschitz, beta0, *, max_iter: int = 20000,
@@ -182,12 +182,12 @@ def fista_nn_lasso(X, y, lam, lipschitz, beta0, *, max_iter: int = 20000,
     def body(state):
         carry, it, _ = state
         carry, _ = jax.lax.scan(inner, carry, None, length=check_every)
-        pval, dval, _ = _nn_gap(X, y, lam, carry[0])
-        return carry, it + check_every, (pval - dval).astype(dtype)
+        gap, _ = _nn_gap(X, y, lam, carry[0])
+        return carry, it + check_every, gap.astype(dtype)
 
     init = ((beta0, beta0, jnp.asarray(1.0, dtype)), jnp.asarray(0), jnp.asarray(jnp.inf, dtype))
     (beta, _, _), iters, gap = jax.lax.while_loop(cond, body, init)
-    _, _, theta = _nn_gap(X, y, lam, beta)
+    _, theta = _nn_gap(X, y, lam, beta)
     return SolveResult(beta, theta, gap, iters)
 
 

@@ -32,7 +32,11 @@ from typing import Optional
 import jax
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-HISTORY = 256          # top-level calls that ``history()`` keeps
+# top-level calls that ``history()`` keeps: a path call makes two
+# (``session.init``, ``path``), so 1024 calls, four times the most (252)
+# a 51-s window of the image-dictionary path cell held on one v5e; about
+# 13 MB
+HISTORY = 2048
 
 
 @dataclasses.dataclass

@@ -156,6 +156,15 @@ class FeatureShardPlan:
             out[..., c0:c0 + w] = a[s, ..., :w]
         return out
 
+    def stacked_index(self, cols) -> np.ndarray:
+        """Original columns -> their positions in the flattened
+        (S * p_shard) stacked layout."""
+        cols = np.asarray(cols)
+        s = np.searchsorted(np.asarray(self.col_starts), cols,
+                            side="right") - 1
+        return (s * self.p_shard + cols
+                - np.asarray(self.col_starts)[s]).astype(np.int32)
+
     def shard_groups(self, a) -> np.ndarray:
         """(..., G) -> (S, ..., G_shard) host scatter (contiguous blocks,
         no padding — every shard owns exactly ``units_per_shard`` groups)."""
