@@ -58,6 +58,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
+import weakref
 from typing import Optional
 
 import numpy as np
@@ -152,6 +154,77 @@ def _put(a, dtype=None):
     out = jnp.asarray(a, dtype)
     spans.add("h2d_bytes", out.nbytes)
     return out
+
+
+class DesignNorms:
+    """The norms of a design array that no response enters, computed once
+    per array: its column norms, its group norms per (partition, method),
+    and ``||X||^2`` once a full bucket has read it.
+
+    An entry is keyed on the identity of the array ``X`` that reaches the
+    engine, holds ``X`` by weak reference only and is dropped when ``X`` is
+    freed.  It holds p + G floats a partition and a scalar, never ``X``.
+    Group and feature weights do not enter the norms, so a reweighted
+    ``GroupSpec`` over the same partition reads the same entry.  One
+    instance serves the process (``DESIGN_NORMS``): a deployment opens a
+    session per response on one shared design, so a cache per session
+    would never be read twice."""
+
+    MAX_VALUES = 8          # per design; the oldest goes first
+
+    def __init__(self):
+        self._entries: dict = {}     # id(X) -> (weakref to X, {key: value})
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def _values(self, X) -> dict:
+        key = id(X)
+        found = self._entries.get(key)
+        if found is not None and found[0]() is X:
+            return found[1]
+        entries = self._entries
+
+        def drop(ref, key=key):
+            # runs while X is freed, before its id can be reused
+            if entries.get(key, (None,))[0] is ref:
+                del entries[key]
+
+        values: dict = {}
+        entries[key] = (weakref.ref(X, drop), values)
+        return values
+
+    def lookup(self, X, key, compute):
+        """(value, 1) if ``X``'s entry holds ``key``, else (``compute()``,
+        0), kept under ``key``; the second item counts the cache hit."""
+        with self._lock:
+            values = self._values(X)
+            if key in values:
+                return values[key], 1
+        value = compute()
+        with self._lock:
+            if len(values) >= self.MAX_VALUES:
+                del values[next(iter(values))]
+            values[key] = value
+        return value, 0
+
+
+DESIGN_NORMS = DesignNorms()
+
+
+def _group_norms_key(spec: GroupSpec, specnorm_method: str):
+    """``DesignNorms`` key of ``spec``'s group norms: the method and the
+    partition (contiguous groups, so their sizes)."""
+    method = "power" if specnorm_method == "power" else "frobenius"
+    return ("group_norms", method, _pull(spec.sizes).tobytes())
+
+
+# X^T r0 for a call given none, and the zero-solution anchor (lambda_max,
+# its argmax): one dispatch each, and no transposed copy of X
+_xt_r0 = jax.jit(lambda X, r0: mm(X.T, r0))
+_lambda_max_sgl_jit = jax.jit(lambda_max_sgl)
+_lambda_max_nn_jit = jax.jit(lambda_max_nn)
 
 
 def _xtv(X, v, use_pallas: bool):
@@ -571,7 +644,7 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
                      margin: float = 0.125, chunk_init: int = 8,
                      feature_shards: int = 0,
                      compile_keys: Optional[set] = None,
-                     loss=SQUARED) -> PathResult:
+                     loss=SQUARED, xty=None) -> PathResult:
     """Batched SGL path: grid screening, speculative bucketed sweeps with
     in-scan certification.
 
@@ -602,16 +675,27 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
     (TLFre's Theorem-12 ball is squared-loss algebra) and run the pure-jnp
     route (no Pallas kernels, no feature shards).
 
+    ``xty`` (optional, (p,)) is X^T r0, r0 the negative gradient of the
+    loss at beta = 0 (y for the squared loss), as a session already holds
+    it; without it the call computes it.  The X-only norms come from
+    ``DESIGN_NORMS``: computed on the first call that meets the array
+    ``X`` (and the partition), read on later ones.  ``||X||^2`` is
+    computed only where a bucket is the full set.  The feature-sharded
+    route computes its own X^T r0 and norms.
+
     The call records its spans (``core.spans``) in ``PathResult.spans``:
     ``path`` > ``setup`` (> ``setup.xty``, ``setup.col_norms``,
-    ``setup.group_norms``, ``setup.spectral_norm``), ``host_copy``, and one
-    ``segment`` per loop pass (> ``segment.screen``, ``segment.expand``,
-    ``segment.gather``, ``segment.sweep``, ``segment.assemble``), with the
-    array bytes each moves between host and device (``h2d_bytes``,
-    ``d2h_bytes``), the sweep's ``rows_solved``, ``rows_accepted`` and
-    ``rows_capped`` (solved rows whose iterations reached ``max_iter``), and
-    on ``setup.group_norms`` the ``gram_groups`` whose norms came from
-    banded Gram blocks (``linalg.gram_groups``).
+    ``setup.group_norms``), ``host_copy``, and one ``segment`` per loop
+    pass (> ``segment.screen``, ``segment.expand``, ``segment.gather``,
+    ``segment.sweep``, ``segment.assemble``; a full-set bucket's
+    ``segment.gather`` > ``setup.spectral_norm``), with the array bytes
+    each moves between host and device (``h2d_bytes``, ``d2h_bytes``), the
+    sweep's ``rows_solved``, ``rows_accepted`` and ``rows_capped`` (solved
+    rows whose iterations reached ``max_iter``), on ``setup.group_norms``
+    the ``gram_groups`` whose norms came from banded Gram blocks
+    (``linalg.gram_groups``), and ``design_cache_hits``, the X-only
+    quantities read from ``DESIGN_NORMS``, on ``setup`` (column and group
+    norms) and ``setup.spectral_norm`` (``||X||^2``).
     """
     if screen not in ("tlfre", "gapsafe", "none"):
         raise ValueError(f"unknown screen mode {screen!r}")
@@ -674,27 +758,30 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
                 np.asarray(spec.group_ids) + 1) - 1)            # pads -> -1
             n_boundary = jax.block_until_ready(_fs.sharded_fit(
                 fops, Xs, jnp.where(gid_stack == g_star, w_s, 0.0)))
-            L_full = None          # only the full-bucket fallback needs it
             r0 = y                 # sharded route is squared-loss only
         else:
             with spans.span("setup.xty"):
-                # -grad of the loss at beta = 0; y itself for squared loss,
-                # so the squared setup GEMV X.T @ y is unchanged
+                # -grad of the loss at beta = 0; y itself for squared loss
                 r0 = loss.residual_at_zero(y)
-                xty = mm(X.T, r0)
-                lam_max, g_star = lambda_max_sgl(spec, xty, alpha)
+                if xty is None:
+                    xty = _xt_r0(X, r0)
+                lam_max, g_star = _lambda_max_sgl_jit(spec, xty, alpha)
                 lam_max = float(lam_max)
             with spans.span("setup.col_norms"):
-                col_n = jax.block_until_ready(column_norms(X))
+                col_n, hit_c = DESIGN_NORMS.lookup(
+                    X, "col_norms", lambda: column_norms(X))
+                jax.block_until_ready(col_n)
+            g_key = _group_norms_key(spec, specnorm_method)
             with spans.span("setup.group_norms"):
                 spans.add("gram_groups", n_gram)
                 if specnorm_method == "power":
-                    gspec = group_spectral_norms(X, spec)
+                    norms_of = group_spectral_norms
                 else:
-                    gspec = group_frobenius_norms(X, spec)
+                    norms_of = group_frobenius_norms
+                gspec, hit_g = DESIGN_NORMS.lookup(
+                    X, g_key, lambda: norms_of(X, spec))
                 jax.block_until_ready(gspec)
-            with spans.span("setup.spectral_norm"):
-                L_full = jax.block_until_ready(spectral_norm(X) ** 2)
+            spans.add("design_cache_hits", hit_c + hit_g)
 
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max, n_lambdas, min_ratio)
@@ -838,10 +925,12 @@ def sgl_path_batched(X, y, spec: GroupSpec, alpha, *, lambdas=None,
             with spans.span("segment.gather"):
                 if S.all():
                     sub_spec, col_idx = spec, np.arange(p)
-                    if L_full is None:
-                        L_full = jax.block_until_ready(
-                            spectral_norm(X) ** 2)
-                    X_sub, L_sub = X, L_full
+                    with spans.span("setup.spectral_norm"):
+                        L_sub, hit = DESIGN_NORMS.lookup(
+                            X, "sq_norm", lambda: spectral_norm(X) ** 2)
+                        jax.block_until_ready(L_sub)
+                        spans.add("design_cache_hits", hit)
+                    X_sub = X
                     p_b, g_b = p, G
                 else:
                     sub_spec, col_idx = spec.bucketed_subset(S, p_b, g_b)
@@ -949,13 +1038,15 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                           use_pallas: Optional[bool] = None,
                           min_bucket: int = 64, margin: float = 0.125,
                           chunk_init: int = 8, feature_shards: int = 0,
-                          compile_keys: Optional[set] = None) -> PathResult:
+                          compile_keys: Optional[set] = None,
+                          xty=None) -> PathResult:
     """Batched nonnegative-Lasso path: whole-grid DPC / Gap-Safe rules,
     speculative bucketed sweeps with in-scan certification.
-    ``feature_shards`` / ``compile_keys`` and the spans as in
-    ``sgl_path_batched`` (no ``setup.group_norms``; ``margin_fill_nn`` runs
-    in ``segment.expand``; the nn partition is singleton-column: equal
-    blocks when the shard count divides p, degraded otherwise)."""
+    ``feature_shards`` / ``compile_keys`` / ``xty`` (here X^T y), the
+    design cache and the spans as in ``sgl_path_batched`` (no
+    ``setup.group_norms``; ``margin_fill_nn`` runs in ``segment.expand``;
+    the nn partition is singleton-column: equal blocks when the shard
+    count divides p, degraded otherwise)."""
     if screen not in ("dpc", "gapsafe", "none"):
         raise ValueError(f"unknown screen mode {screen!r}")
     X = jnp.asarray(X)
@@ -986,16 +1077,17 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
                     _fs.sharded_column_norms(fops, Xs))
             # Theorem-21 boundary normal is the argmax COLUMN — host gather
             x_star = jnp.asarray(np.asarray(X)[:, int(i_star)])
-            L_full = None
         else:
             with spans.span("setup.xty"):
-                xty = mm(X.T, y)
-                lam_max, i_star = lambda_max_nn(xty)
+                if xty is None:
+                    xty = _xt_r0(X, y)
+                lam_max, i_star = _lambda_max_nn_jit(xty)
                 lam_max = float(lam_max)
             with spans.span("setup.col_norms"):
-                col_n = jax.block_until_ready(column_norms(X))
-            with spans.span("setup.spectral_norm"):
-                L_full = jax.block_until_ready(spectral_norm(X) ** 2)
+                col_n, hit = DESIGN_NORMS.lookup(
+                    X, "col_norms", lambda: column_norms(X))
+                jax.block_until_ready(col_n)
+            spans.add("design_cache_hits", hit)
         if lam_max <= 0:
             raise ValueError("max_i <x_i, y> <= 0: nonnegative Lasso "
                              "solution is identically zero for every "
@@ -1100,10 +1192,12 @@ def nn_lasso_path_batched(X, y, *, lambdas=None, n_lambdas: int = 100,
             with spans.span("segment.gather"):
                 if S.all():
                     col_idx = np.arange(p)
-                    if L_full is None:
-                        L_full = jax.block_until_ready(
-                            spectral_norm(X) ** 2)
-                    X_sub, L_sub = X, L_full
+                    with spans.span("setup.spectral_norm"):
+                        L_sub, hit = DESIGN_NORMS.lookup(
+                            X, "sq_norm", lambda: spectral_norm(X) ** 2)
+                        jax.block_until_ready(L_sub)
+                        spans.add("design_cache_hits", hit)
+                    X_sub = X
                     p_b = p
                 else:
                     col_idx = np.nonzero(S)[0]

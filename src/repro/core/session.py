@@ -151,8 +151,8 @@ class SGLSession:
                 self._xty = mm(problem.X.T, get_loss(
                     problem.loss).residual_at_zero(problem.y))
             # ends the span at the device work; it also frees this GEMV's
-            # transposed copy of X before an engine call makes its own, so
-            # at most one such copy is live at a time
+            # transposed copy of X before an engine call runs (``path``
+            # hands the engine this X^T r0, so the engine makes no copy)
             jax.block_until_ready(self._xty)
         self._last_cv: Optional[_CVState] = None
 
@@ -264,7 +264,8 @@ class SGLSession:
                 min_group_bucket=plan.min_group_bucket, margin=plan.margin,
                 chunk_init=plan.chunk_init,
                 feature_shards=plan.feature_shards,
-                compile_keys=self.compile_keys, loss=loss)
+                compile_keys=self.compile_keys, loss=loss,
+                xty=self._xty if loss == prob.loss else None)
         else:
             res = nn_lasso_path_batched(
                 prob.X, prob.y, lambdas=plan.lambdas,
@@ -274,7 +275,7 @@ class SGLSession:
                 use_pallas=plan.use_pallas, min_bucket=plan.min_bucket,
                 margin=plan.margin, chunk_init=plan.chunk_init,
                 feature_shards=plan.feature_shards,
-                compile_keys=self.compile_keys)
+                compile_keys=self.compile_keys, xty=self._xty)
         self._absorb(res.stats)
         return res
 

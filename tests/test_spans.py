@@ -56,9 +56,8 @@ def test_a_path_records_one_tree(engine):
     assert set(top[2:]) == {"segment"}
     assert len(top) - 2 == res.stats.n_segments
     setup = [s.name for s in _children(rec, 1)]
-    assert setup == (["setup.xty", "setup.col_norms", "setup.group_norms",
-                      "setup.spectral_norm"] if engine == "sgl" else
-                     ["setup.xty", "setup.col_norms", "setup.spectral_norm"])
+    assert setup == (["setup.xty", "setup.col_norms", "setup.group_norms"]
+                     if engine == "sgl" else ["setup.xty", "setup.col_norms"])
     for i, s in enumerate(rec):
         if s.name == "segment":
             assert tuple(c.name for c in _children(rec, i)) == SEGMENT_PARTS
@@ -73,7 +72,7 @@ def test_timers_are_span_sums(engine):
     assert res.solve_time == spans.total(rec, "segment.gather",
                                          "segment.sweep")
     parts = spans.total(rec, "setup.xty", "setup.col_norms",
-                        "setup.group_norms", "setup.spectral_norm")
+                        "setup.group_norms")
     assert 0 < parts <= res.setup_time
 
 
